@@ -38,7 +38,8 @@ Modes:
                         codec-kernel microbench: fused Pallas codec
                         lowerings vs the jnp reference path per fused
                         codec (wall-clock both jitted, analytic HBM
-                        traffic per stage, roofline seconds at HBM_BW),
+                        traffic per stage, roofline seconds at v5e's
+                        HBM bandwidth),
                         asserting the fused encode pass moves <= half the
                         jnp path's bytes; with OUT.json, merges a
                         "codec_kernels" section into the artifact and
@@ -70,11 +71,13 @@ from repro.core import (artifact as artifact_schema, autotune, compress,
 from repro.core.comm import Communicator
 from repro.core.topology import Topology
 from repro.distributed import backend as dist_backend
-from repro.launch.mesh import make_process_mesh
+from repro.launch import cache as compile_cache
+from repro.launch.mesh import make_mesh, make_process_mesh
 
 # must run before the first device query: under the repro.distributed
 # launcher this joins the multi-controller runtime (no-op otherwise)
 BACKEND = dist_backend.auto_initialize()
+compile_cache.enable()
 
 DC = jax.device_count()
 if BACKEND.multiprocess:
@@ -85,7 +88,7 @@ if BACKEND.multiprocess:
 else:
     P = 2 if DC % 2 == 0 else 1
     N = DC // P
-    mesh = jax.make_mesh((N, P), ("node", "local"))
+    mesh = make_mesh((N, P), ("node", "local"))
 topo = Topology.from_mesh(mesh)
 comm = Communicator(mesh, topo)
 
@@ -574,7 +577,8 @@ def codec_kernel_mode(out_path=None):
     compress.jnp_reference_paths() so its compiled program never routes a
     kernel), then report the ANALYTIC memory traffic per stage
     (kernels.codec.memory_traffic — the HBM passes each path makes) and
-    the roofline seconds those bytes cost at HBM_BW. On CPU the fused
+    the roofline seconds those bytes cost at v5e's HBM bandwidth
+    (``roofline.terms.PEAKS``). On CPU the fused
     kernels run in interpret mode, so wall-clock favors jnp — the traffic
     model is the TPU-relevant number, and the acceptance bar (fused moves
     <= half the jnp bytes on at least one codec) is asserted here.
@@ -585,8 +589,9 @@ def codec_kernel_mode(out_path=None):
     results/BENCH_codec_kernels.json next to it.
     """
     from repro.kernels import codec as ckern
-    from repro.roofline.terms import HBM_BW
+    from repro.roofline import terms
 
+    hbm_bw = terms.peaks(terms.V5E).hbm_bw  # analytic: modeled on v5e
     S, W = 8, 8
     L = 16 * compress.BLOCK          # 4096 elems/slice, 32 KiB wire payload
     n_elems = S * L
@@ -620,7 +625,7 @@ def codec_kernel_mode(out_path=None):
                                              "jnp": us_j_dr}},
                "traffic": traffic,
                "roofline_s": {
-                   stage: {path: traffic[stage][f"{path}_bytes"] / HBM_BW
+                   stage: {path: traffic[stage][f"{path}_bytes"] / hbm_bw
                            for path in ("jnp", "fused")}
                    for stage in traffic}}
         rows.append(row)

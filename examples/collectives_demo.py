@@ -18,9 +18,10 @@ import numpy as np
 from repro.core import autotune, compress, costmodel, mcoll
 from repro.core.comm import Communicator
 from repro.core.topology import Topology
+from repro.launch.mesh import make_mesh
 
 N, P = 4, 2
-mesh = jax.make_mesh((N, P), ("node", "local"))
+mesh = make_mesh((N, P), ("node", "local"))
 topo = Topology(N, P)
 comm = Communicator(mesh, topo)
 x = jnp.arange(N * P * 4, dtype=jnp.float32)

@@ -3,9 +3,9 @@ shard_map'd collectives.
 
 This layer owns, for the whole codebase:
 
-  1. **version portability** — all shard_map construction flows through
-     ``repro.core.compat`` (the only module allowed to touch the raw JAX
-     entry point), so a JAX API move is absorbed in one place;
+  1. **one shard_map call site** — all shard_map construction flows
+     through :func:`sharded` (the only place in ``src/`` that names
+     ``jax.shard_map``), so a JAX API move is absorbed in one place;
   2. **wiring** — the per-collective ``body`` / ``in_specs`` / ``out_specs``
      conventions live in the declarative :data:`_WIRING` table instead of
      being re-derived at every call site;
@@ -42,7 +42,7 @@ Public API:
   * :func:`compile_persistent` — AOT-compile one plan for a fixed
     shape/dtype with a pinned input sharding (the ``PersistentOp`` backend;
     entries share the exec cache, so re-initialising an op is a hit).
-  * :func:`sharded` — version-portable shard_map for custom bodies (MoE
+  * :func:`sharded` — the one shard_map wrapper for custom bodies (MoE
     expert-parallel dispatch, the manual train step, ad-hoc checks).
   * :func:`calibrate` — timed sweeps feeding the selector's tuning table.
   * :func:`cache_stats` / :func:`selection_stats` / :func:`clear_cache` —
@@ -63,7 +63,7 @@ import numpy as np
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.core import autotune, compat
+from repro.core import autotune
 from repro.core import compress as _codecs
 from repro.core import mcoll as _mcoll
 from repro.core import telemetry as _tm
@@ -72,19 +72,20 @@ from repro.core.topology import Topology
 AUTO = "auto"
 
 # ---------------------------------------------------------------------------
-# version-portable shard_map for custom bodies
+# the one shard_map call site
 # ---------------------------------------------------------------------------
 
 
 def sharded(body: Callable, mesh, in_specs: Any, out_specs: Any,
             check: bool = False) -> Callable:
-    """Wrap ``body`` with a version-portable shard_map over ``mesh``.
+    """Wrap ``body`` with ``jax.shard_map`` over ``mesh``; ``check`` is its
+    ``check_vma`` (verify the varying-manual-axes typing of the outputs).
 
     This is the supported way to shard_map a custom body anywhere in the
-    codebase; it keeps direct JAX-API references confined to ``compat``.
+    codebase; it keeps the direct JAX-API reference in this one function.
     """
-    return compat.shard_map(body, mesh, in_specs=in_specs,
-                            out_specs=out_specs, check_vma=check)
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
 
 
 # ---------------------------------------------------------------------------

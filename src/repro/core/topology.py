@@ -26,6 +26,12 @@ from typing import Optional, Set, Tuple
 #: folklore without drowning in repeats)
 _FALLBACK_WARNED: Set[str] = set()
 
+#: TPU ``device_kind`` -> (link preset within a slice, across processes or
+#: slices). A TPU kind not listed here has no cost-model preset and raises.
+TPU_LINKS = {
+    "TPU v5 lite": ("tpu_v5e_ici", "tpu_v5e_dcn"),
+}
+
 
 def _axis_crossings(mesh, axis: str) -> Set[str]:
     """Boundary fields (``process_index`` / ``slice_index``) that vary along
@@ -69,7 +75,9 @@ def derive_link(mesh, axis: str, level: str) -> str:
     on. Then the platform names the preset:
 
       * cpu:  cross-process -> "host_ipc", in-process -> "host_cpu"
-      * tpu:  cross-process/slice -> "tpu_v5e_dcn", else -> "tpu_v5e_ici"
+      * tpu:  keyed on ``device_kind`` through :data:`TPU_LINKS` —
+        cross-process/slice -> its DCN preset, else its ICI preset; a kind
+        with no row raises (its links were never measured or modeled)
       * anything else: classified the same way from process boundaries but
         mapped onto the host presets, with a once-per-platform warning so
         calibration tables record which rows rest on folklore constants.
@@ -87,7 +95,12 @@ def derive_link(mesh, axis: str, level: str) -> str:
     if platform == "cpu":
         return "host_ipc" if "process_index" in crossed else "host_cpu"
     if platform == "tpu":
-        return "tpu_v5e_dcn" if crossed else "tpu_v5e_ici"
+        kind = getattr(dev0, "device_kind", None)
+        if kind not in TPU_LINKS:
+            raise ValueError(f"derive_link: no link presets for TPU device "
+                             f"kind {kind!r}; known: {sorted(TPU_LINKS)}")
+        ici, dcn = TPU_LINKS[kind]
+        return dcn if crossed else ici
     link = "host_ipc" if "process_index" in crossed else "host_cpu"
     _warn_fallback(platform, link)
     return link

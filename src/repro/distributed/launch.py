@@ -1,12 +1,16 @@
 """Launcher for local multi-controller SPMD: spawn K coordinated processes.
 
 Each worker process gets, via its environment (so ordering can never go
-wrong): ``XLA_FLAGS`` forcing its own host CPU device count,
+wrong): ``JAX_PLATFORMS=cpu`` and ``XLA_FLAGS`` forcing its own host CPU
+device count,
 ``REPRO_DIST_PROCS`` / ``REPRO_DIST_RANK`` / ``REPRO_DIST_COORD`` /
 ``REPRO_DIST_SCRATCH`` (the contract :func:`repro.distributed.backend
 .auto_initialize` reads), and ``PYTHONPATH`` including ``src/``. The
 coordinator is rank 0's ``jax.distributed.initialize`` service on a free
 loopback port picked by the parent.
+
+The launcher is for CPU processes only: a chip belongs to one process, and
+on a TPU host one process drives all of its chips (``chip_smoke.py``).
 
 Two entry styles:
 
@@ -62,6 +66,7 @@ def _worker_env(base: Dict[str, str], rank: int, processes: int,
     flags = _FORCE_FLAG.sub("", env.get("XLA_FLAGS", "")).strip()
     env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count="
                         f"{int(devices_per_process)} " + flags).strip()
+    env["JAX_PLATFORMS"] = "cpu"
     env[_backend.ENV_PROCS] = str(int(processes))
     env[_backend.ENV_RANK] = str(int(rank))
     env[_backend.ENV_COORD] = coord

@@ -26,9 +26,13 @@ through the lowering when ``CodecMeta.fused`` advertises it (and the
 module-level fused toggle is on — ``compress.jnp_reference_paths()`` is the
 A/B escape hatch conformance uses).
 
-Backend dispatch follows ``kernels/ops.py``: compiled Pallas on TPU,
-``interpret=True`` elsewhere — CPU CI runs the same kernel bodies through
-the interpreter, so the fused paths are conformance-tested everywhere.
+Every kernel walks its payload as rows of one ``BLOCK``-element
+quantization block each, a tile of up to 512 rows per grid step; the
+per-block max is a row reduction and the scales leave as an ``(R, 1)``
+vector block. Backend dispatch follows ``kernels/ops.py``: compiled Pallas
+on TPU, interpreted on the CPU backend — CPU CI runs the same kernel bodies
+through the interpreter, so the fused paths are conformance-tested
+everywhere.
 
 :func:`memory_traffic` is the analytic per-stage HBM byte count (jnp passes
 vs fused passes) the codec-kernel microbench and the cost model's
@@ -46,21 +50,144 @@ from jax import lax
 from jax.experimental import pallas as pl
 
 from repro.core.compress import BLOCK
+from repro.kernels.ops import interpret as _interpret
 
 _FP8_MAX = 448.0  # e4m3 finite max (matches compress.Fp8SimCodec)
 _HAVE_FP8 = hasattr(jnp, "float8_e4m3fn")
+_HALF = BLOCK // 2
+
+# Each grid step handles a tile of R quantization blocks laid out as rows
+# of an (N, BLOCK) array. R is a multiple of 32 so int8/uint8 tiles fill
+# whole (32, 128) vregs (f32 tiles need only 8); 512 rows keep a step's
+# double-buffered tiles at a few MiB of VMEM.
+_ROW_ALIGN = 32
+_MAX_ROWS = 512
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
 
 
-def _pad_blocks(x2d):
-    """Pad (S, L) f32 to a whole number of BLOCK-element blocks."""
-    x2d = jnp.asarray(x2d).astype(jnp.float32)
-    S, L = x2d.shape
+def _tile_rows(n_rows: int) -> int:
+    return min(_MAX_ROWS, _round_up(max(n_rows, 1), _ROW_ALIGN))
+
+
+def _pad_axis(a, axis: int, n: int):
+    pad = [(0, 0)] * a.ndim
+    pad[axis] = (0, n - a.shape[axis])
+    return jnp.pad(a, pad)
+
+
+def _as_rows(a, L: int):
+    """(S, L) -> (S * nb, BLOCK) f32, zero-padded: one block per row."""
+    a = jnp.asarray(a).astype(jnp.float32)
     nb = -(-L // BLOCK)
-    return jnp.pad(x2d, ((0, 0), (0, nb * BLOCK - L))), nb
+    return _pad_axis(a, 1, nb * BLOCK).reshape(a.shape[0] * nb, BLOCK), nb
+
+
+def _encode_call(quant, rows, wire_dtype, wire_cols: int, interpret: bool,
+                 scale_rows=None):
+    """One fused pass over (N, BLOCK) rows: read the payload (plus the
+    carried residual when ``rows`` holds two arrays) once and write
+    wire ``(N, wire_cols)``, scale ``(N, 1)`` and residual ``(N, BLOCK)``.
+
+    ``quant(c, scale)`` maps a corrected (R, BLOCK) tile to ``(wire,
+    scale, dequantized)``; ``scale`` is the (R, 1) per-row scale when the
+    caller supplies ``scale_rows``, else None (the kernel derives it as a
+    row reduction and writes it out)."""
+    N = rows[0].shape[0]
+    R = _tile_rows(N)
+    Np = _round_up(N, R)
+    row = pl.BlockSpec((R, BLOCK), lambda i: (i, 0))
+    col = pl.BlockSpec((R, 1), lambda i: (i, 0))
+    args = [_pad_axis(a, 0, Np) for a in rows]
+    given = scale_rows is not None
+    if given:
+        args.append(_pad_axis(scale_rows, 0, Np))
+    n_x = len(rows)
+
+    def kernel(*refs):
+        c = refs[0][...]
+        for r in refs[1:n_x]:
+            c = c + r[...]
+        wire, scale, deq = quant(c, refs[n_x][...] if given else None)
+        outs = refs[len(args):]
+        outs[0][...] = wire
+        if not given:
+            outs[1][...] = scale
+        outs[-1][...] = c - deq
+
+    out_specs = [pl.BlockSpec((R, wire_cols), lambda i: (i, 0))]
+    out_shape = [jax.ShapeDtypeStruct((Np, wire_cols), wire_dtype)]
+    if not given:
+        out_specs.append(col)
+        out_shape.append(jax.ShapeDtypeStruct((Np, 1), jnp.float32))
+    out_specs.append(row)
+    out_shape.append(jax.ShapeDtypeStruct((Np, BLOCK), jnp.float32))
+    outs = pl.pallas_call(
+        kernel, grid=(Np // R,),
+        in_specs=[row] * n_x + ([col] if given else []),
+        out_specs=out_specs, out_shape=out_shape, interpret=interpret,
+    )(*args)
+    return [o[:N] for o in outs]
+
+
+def _decode_call(dequant, wire3, scale3, interpret: bool):
+    """Fused decode + sum over the leading wire-peer axis: wire
+    ``(W, nb, C)`` and per-row scales ``(W, nb, 1)`` accumulate into an
+    f32 ``(nb, BLOCK)`` output tile revisited along the inner grid axis."""
+    W, nb, C = wire3.shape
+    R = _tile_rows(nb)
+    nbp = _round_up(nb, R)
+
+    def kernel(q_ref, s_ref, o_ref):
+        @pl.when(pl.program_id(1) == 0)
+        def _init():
+            o_ref[...] = jnp.zeros_like(o_ref)
+
+        o_ref[...] += dequant(q_ref[0]) * s_ref[0]
+
+    out = pl.pallas_call(
+        kernel, grid=(nbp // R, W),
+        in_specs=[pl.BlockSpec((1, R, C), lambda b, w: (w, b, 0)),
+                  pl.BlockSpec((1, R, 1), lambda b, w: (w, b, 0))],
+        out_specs=pl.BlockSpec((R, BLOCK), lambda b, w: (b, 0)),
+        out_shape=jax.ShapeDtypeStruct((nbp, BLOCK), jnp.float32),
+        interpret=interpret,
+    )(_pad_axis(wire3, 1, nbp), _pad_axis(scale3, 1, nbp))
+    return out[:nb].reshape(-1)
+
+
+def _block_encoder(quant, wire_dtype, wire_cols: int):
+    """Jitted ``encode(x2d[, err], *, interpret) -> (comp, residual)`` for
+    a per-block codec: with ``err`` it is encode + error feedback, without
+    it encode + round-trip residual."""
+
+    @functools.partial(jax.jit, static_argnames=("interpret",))
+    def encode(*xs, interpret: bool):
+        S, L = xs[0].shape
+        rows = [_as_rows(a, L)[0] for a in xs]
+        nb = rows[0].shape[0] // S
+        q, scale, res = _encode_call(quant, rows, wire_dtype, wire_cols,
+                                     interpret)
+        return ({"q": q.reshape(S, nb, wire_cols),
+                 "scale": scale.reshape(S, nb)},
+                res.reshape(S, nb * BLOCK)[:, :L])
+
+    return encode
+
+
+def _block_decoder(dequant):
+    """Jitted ``decode_reduce(comp, length, *, interpret) -> (length,)``
+    for a per-block wire form ``{"q": (W, nb, C), "scale": (W, nb)}``."""
+
+    @functools.partial(jax.jit, static_argnames=("length", "interpret"))
+    def decode_reduce(comp, length: int, *, interpret: bool):
+        scale = comp["scale"]
+        return _decode_call(dequant, comp["q"], scale[..., None],
+                            interpret)[:length]
+
+    return decode_reduce
 
 
 # ---------------------------------------------------------------------------
@@ -68,259 +195,106 @@ def _pad_blocks(x2d):
 # ---------------------------------------------------------------------------
 
 
-def _i8_store(c, q_ref, s_ref, r_ref):
-    """Shared body: quantize one (1, BLOCK) block of the corrected payload
-    ``c`` and store wire + scale + residual — the same arithmetic as the
-    jnp codec (scale = blockmax/127, round-to-nearest, clamped divisor)."""
-    scale = jnp.max(jnp.abs(c)) / 127.0
+def _i8_quant(c, _):
+    """Same arithmetic as the jnp codec: scale = blockmax/127,
+    round-to-nearest, clamped divisor."""
+    scale = jnp.max(jnp.abs(c), axis=1, keepdims=True) / 127.0
     q = jnp.clip(jnp.round(c / jnp.maximum(scale, 1e-12)), -127, 127)
-    q_ref[...] = q.astype(jnp.int8)
-    s_ref[0, 0] = scale
-    r_ref[...] = c - q * scale
+    return q.astype(jnp.int8), scale, q * scale
 
 
-def _i8_ef_kernel(x_ref, e_ref, q_ref, s_ref, r_ref):
-    _i8_store(x_ref[...] + e_ref[...], q_ref, s_ref, r_ref)
-
-
-def _i8_enc_kernel(x_ref, q_ref, s_ref, r_ref):
-    _i8_store(x_ref[...], q_ref, s_ref, r_ref)
-
-
-def _i8_dr_kernel(q_ref, s_ref, o_ref):
-    w = pl.program_id(1)
-
-    @pl.when(w == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    o_ref[...] += q_ref[...].astype(jnp.float32) * s_ref[0, 0]
-
-
-def _block_encode_call(kernel, inputs, S: int, nb: int, wire_dtype,
-                       wire_cols: int, interpret: bool):
-    """One fused pass over (S, nb) blocks -> (wire, scale, residual)."""
-    n_in = len(inputs)
-    return pl.pallas_call(
-        kernel,
-        grid=(S, nb),
-        in_specs=[pl.BlockSpec((1, BLOCK), lambda s, b: (s, b))] * n_in,
-        out_specs=[
-            pl.BlockSpec((1, wire_cols), lambda s, b: (s, b)),
-            pl.BlockSpec((1, 1), lambda s, b: (s, b)),
-            pl.BlockSpec((1, BLOCK), lambda s, b: (s, b)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((S, nb * wire_cols), wire_dtype),
-            jax.ShapeDtypeStruct((S, nb), jnp.float32),
-            jax.ShapeDtypeStruct((S, nb * BLOCK), jnp.float32),
-        ],
-        interpret=interpret,
-    )(*inputs)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def int8_encode_feedback(x2d, err, *, interpret: bool = True):
-    """Fused encode + error feedback: read x and the carried residual once,
-    emit ({"q", "scale"}, new residual). Matches the jnp
-    ``encode_with_feedback`` contract bit-for-bit in arithmetic."""
-    S, L = x2d.shape
-    xp, nb = _pad_blocks(x2d)
-    ep, _ = _pad_blocks(jnp.asarray(err).astype(jnp.float32))
-    q, scale, res = _block_encode_call(_i8_ef_kernel, (xp, ep), S, nb,
-                                       jnp.int8, BLOCK, interpret)
-    return ({"q": q.reshape(S, nb, BLOCK), "scale": scale}, res[:, :L])
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def int8_encode_residual(x2d, *, interpret: bool = True):
-    """Fused encode + round-trip residual (no feedback input)."""
-    S, L = x2d.shape
-    xp, nb = _pad_blocks(x2d)
-    q, scale, res = _block_encode_call(_i8_enc_kernel, (xp,), S, nb,
-                                       jnp.int8, BLOCK, interpret)
-    return ({"q": q.reshape(S, nb, BLOCK), "scale": scale}, res[:, :L])
-
-
-@functools.partial(jax.jit, static_argnames=("length", "interpret"))
-def int8_decode_reduce(comp, length: int, *, interpret: bool = True):
-    """Fused decode + sum over the leading wire-peer axis: accumulate the
-    int8 wire slices into an f32 register block per grid column."""
-    q3, scale = comp["q"], comp["scale"]
-    W, nb = scale.shape
-    out = pl.pallas_call(
-        _i8_dr_kernel,
-        grid=(nb, W),
-        in_specs=[pl.BlockSpec((1, BLOCK), lambda b, w: (w, b)),
-                  pl.BlockSpec((1, 1), lambda b, w: (w, b))],
-        out_specs=pl.BlockSpec((1, BLOCK), lambda b, w: (0, b)),
-        out_shape=jax.ShapeDtypeStruct((1, nb * BLOCK), jnp.float32),
-        interpret=interpret,
-    )(q3.reshape(W, nb * BLOCK), scale)
-    return out.reshape(-1)[:length]
+int8_encode = _block_encoder(_i8_quant, jnp.int8, BLOCK)
+int8_decode_reduce = _block_decoder(lambda q: q.astype(jnp.float32))
 
 
 # ---------------------------------------------------------------------------
 # int4_block: packed two-per-byte wire form, per-256-block fp32 scale
+#
+# Nibble pairs (+8 bias, even element in the low nibble) are formed and
+# split with 0/1/16 selection matmuls rather than a lane-interleaving
+# reshape, which Mosaic cannot lower. Every operand is a small integer, so
+# the bf16 products and f32 sums are exact.
 # ---------------------------------------------------------------------------
 
-_HALF = BLOCK // 2
+
+def _pack_matrix():
+    """(BLOCK, HALF): lane 2j -> column j with weight 1, lane 2j+1 with 16."""
+    i = lax.broadcasted_iota(jnp.int32, (BLOCK, _HALF), 0)
+    j = lax.broadcasted_iota(jnp.int32, (BLOCK, _HALF), 1)
+    m = jnp.where(i == 2 * j, 1.0, jnp.where(i == 2 * j + 1, 16.0, 0.0))
+    return m.astype(jnp.bfloat16)
 
 
-def _i4_store(c, q_ref, s_ref, r_ref):
-    """Quantize to [-7, 7] against blockmax/7 and pack nibble pairs
-    (+8 bias, even element low nibble) — mirrors Int4BlockCodec.encode."""
-    scale = jnp.max(jnp.abs(c)) / 7.0
+def _unpack_matrix(parity: int):
+    """(HALF, BLOCK): column j -> lane 2j + parity."""
+    j = lax.broadcasted_iota(jnp.int32, (_HALF, BLOCK), 0)
+    k = lax.broadcasted_iota(jnp.int32, (_HALF, BLOCK), 1)
+    return (k == 2 * j + parity).astype(jnp.bfloat16)
+
+
+def _i4_quant(c, _):
+    """Quantize to [-7, 7] against blockmax/7 and pack nibble pairs —
+    mirrors Int4BlockCodec.encode."""
+    scale = jnp.max(jnp.abs(c), axis=1, keepdims=True) / 7.0
     q = jnp.clip(jnp.round(c / jnp.maximum(scale, 1e-12)), -7, 7)
-    r_ref[...] = c - q * scale
-    s_ref[0, 0] = scale
-    pairs = (q.astype(jnp.int32) + 8).reshape(1, _HALF, 2)
-    q_ref[...] = (pairs[..., 0] | (pairs[..., 1] << 4)).astype(jnp.uint8)
+    packed = jnp.dot((q + 8.0).astype(jnp.bfloat16), _pack_matrix(),
+                     preferred_element_type=jnp.float32)
+    return packed.astype(jnp.int32).astype(jnp.uint8), scale, q * scale
 
 
-def _i4_ef_kernel(x_ref, e_ref, q_ref, s_ref, r_ref):
-    _i4_store(x_ref[...] + e_ref[...], q_ref, s_ref, r_ref)
+def _i4_dequant(b):
+    b = b.astype(jnp.int32)
+    lo = ((b & 0xF) - 8).astype(jnp.bfloat16)
+    hi = ((b >> 4) - 8).astype(jnp.bfloat16)
+    return (jnp.dot(lo, _unpack_matrix(0), preferred_element_type=jnp.float32)
+            + jnp.dot(hi, _unpack_matrix(1),
+                      preferred_element_type=jnp.float32))
 
 
-def _i4_enc_kernel(x_ref, q_ref, s_ref, r_ref):
-    _i4_store(x_ref[...], q_ref, s_ref, r_ref)
-
-
-def _i4_dr_kernel(q_ref, s_ref, o_ref):
-    w = pl.program_id(1)
-
-    @pl.when(w == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    b = q_ref[...].astype(jnp.int32)
-    lo = (b & 0xF) - 8
-    hi = (b >> 4) - 8
-    pair = jnp.stack([lo, hi], axis=-1).reshape(1, BLOCK)
-    o_ref[...] += pair.astype(jnp.float32) * s_ref[0, 0]
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def int4_encode_feedback(x2d, err, *, interpret: bool = True):
-    S, L = x2d.shape
-    xp, nb = _pad_blocks(x2d)
-    ep, _ = _pad_blocks(jnp.asarray(err).astype(jnp.float32))
-    q, scale, res = _block_encode_call(_i4_ef_kernel, (xp, ep), S, nb,
-                                       jnp.uint8, _HALF, interpret)
-    return ({"q": q.reshape(S, nb, _HALF), "scale": scale}, res[:, :L])
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def int4_encode_residual(x2d, *, interpret: bool = True):
-    S, L = x2d.shape
-    xp, nb = _pad_blocks(x2d)
-    q, scale, res = _block_encode_call(_i4_enc_kernel, (xp,), S, nb,
-                                       jnp.uint8, _HALF, interpret)
-    return ({"q": q.reshape(S, nb, _HALF), "scale": scale}, res[:, :L])
-
-
-@functools.partial(jax.jit, static_argnames=("length", "interpret"))
-def int4_decode_reduce(comp, length: int, *, interpret: bool = True):
-    q3, scale = comp["q"], comp["scale"]
-    W, nb = scale.shape
-    out = pl.pallas_call(
-        _i4_dr_kernel,
-        grid=(nb, W),
-        in_specs=[pl.BlockSpec((1, _HALF), lambda b, w: (w, b)),
-                  pl.BlockSpec((1, 1), lambda b, w: (w, b))],
-        out_specs=pl.BlockSpec((1, BLOCK), lambda b, w: (0, b)),
-        out_shape=jax.ShapeDtypeStruct((1, nb * BLOCK), jnp.float32),
-        interpret=interpret,
-    )(q3.reshape(W, nb * _HALF), scale)
-    return out.reshape(-1)[:length]
+int4_encode = _block_encoder(_i4_quant, jnp.uint8, _HALF)
+int4_decode_reduce = _block_decoder(_i4_dequant)
 
 
 # ---------------------------------------------------------------------------
-# fp8_sim: e4m3 cast against a per-slice scale (whole-slice blocks — the
-# scale is a slice-level amax, so the natural fused tile is one slice)
+# fp8_sim: e4m3 cast against a per-slice scale. The slice amax is one
+# reduction pass ahead of the kernel; the kernel then runs the same row
+# tiles as the block codecs with each row's slice scale as an input.
 # ---------------------------------------------------------------------------
 
 
-def _fp8_store(c, q_ref, s_ref, r_ref):
-    amax = jnp.max(jnp.abs(c))
-    scale = jnp.maximum(amax / _FP8_MAX, 1e-30)
+def _fp8_quant(c, scale):
     q = jnp.clip(c / scale, -_FP8_MAX, _FP8_MAX)
     f8 = q.astype(jnp.float8_e4m3fn)
-    q_ref[...] = lax.bitcast_convert_type(f8, jnp.uint8)
-    s_ref[0, 0] = scale
-    r_ref[...] = c - f8.astype(jnp.float32) * scale
+    return (lax.bitcast_convert_type(f8, jnp.uint8), scale,
+            f8.astype(jnp.float32) * scale)
 
 
-def _fp8_ef_kernel(x_ref, e_ref, q_ref, s_ref, r_ref):
-    _fp8_store(x_ref[...] + e_ref[...], q_ref, s_ref, r_ref)
-
-
-def _fp8_enc_kernel(x_ref, q_ref, s_ref, r_ref):
-    _fp8_store(x_ref[...], q_ref, s_ref, r_ref)
-
-
-def _fp8_dr_kernel(q_ref, s_ref, o_ref):
-    w = pl.program_id(0)
-
-    @pl.when(w == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    f8 = lax.bitcast_convert_type(q_ref[...], jnp.float8_e4m3fn)
-    o_ref[...] += f8.astype(jnp.float32) * s_ref[0, 0]
-
-
-def _fp8_encode_call(kernel, inputs, S: int, L: int, interpret: bool):
-    n_in = len(inputs)
-    return pl.pallas_call(
-        kernel,
-        grid=(S,),
-        in_specs=[pl.BlockSpec((1, L), lambda s: (s, 0))] * n_in,
-        out_specs=[
-            pl.BlockSpec((1, L), lambda s: (s, 0)),
-            pl.BlockSpec((1, 1), lambda s: (s, 0)),
-            pl.BlockSpec((1, L), lambda s: (s, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((S, L), jnp.uint8),
-            jax.ShapeDtypeStruct((S, 1), jnp.float32),
-            jax.ShapeDtypeStruct((S, L), jnp.float32),
-        ],
-        interpret=interpret,
-    )(*inputs)
+def _fp8_dequant(q):
+    return lax.bitcast_convert_type(q, jnp.float8_e4m3fn).astype(jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def fp8_encode_feedback(x2d, err, *, interpret: bool = True):
-    S, L = x2d.shape
-    x = jnp.asarray(x2d).astype(jnp.float32)
-    e = jnp.asarray(err).astype(jnp.float32)
-    q, scale, res = _fp8_encode_call(_fp8_ef_kernel, (x, e), S, L, interpret)
-    return ({"q": q, "scale": scale.reshape(S)}, res)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def fp8_encode_residual(x2d, *, interpret: bool = True):
-    S, L = x2d.shape
-    x = jnp.asarray(x2d).astype(jnp.float32)
-    q, scale, res = _fp8_encode_call(_fp8_enc_kernel, (x,), S, L, interpret)
-    return ({"q": q, "scale": scale.reshape(S)}, res)
+def fp8_encode(*xs, interpret: bool):
+    S, L = xs[0].shape
+    rows, nb = zip(*(_as_rows(a, L) for a in xs))
+    c = rows[0] if len(rows) == 1 else rows[0] + rows[1]
+    amax = jnp.max(jnp.abs(c).reshape(S, -1), axis=1)
+    scale = jnp.maximum(amax / _FP8_MAX, 1e-30)
+    q, res = _encode_call(_fp8_quant, list(rows), jnp.uint8, BLOCK,
+                          interpret,
+                          scale_rows=jnp.repeat(scale, nb[0])[:, None])
+    return ({"q": q.reshape(S, nb[0] * BLOCK)[:, :L], "scale": scale},
+            res.reshape(S, nb[0] * BLOCK)[:, :L])
 
 
 @functools.partial(jax.jit, static_argnames=("length", "interpret"))
-def fp8_decode_reduce(comp, length: int, *, interpret: bool = True):
+def fp8_decode_reduce(comp, length: int, *, interpret: bool):
     q, scale = comp["q"], comp["scale"]
     W, L = q.shape
-    out = pl.pallas_call(
-        _fp8_dr_kernel,
-        grid=(W,),
-        in_specs=[pl.BlockSpec((1, L), lambda w: (w, 0)),
-                  pl.BlockSpec((1, 1), lambda w: (w, 0))],
-        out_specs=pl.BlockSpec((1, L), lambda w: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, L), jnp.float32),
-        interpret=interpret,
-    )(q, scale.reshape(W, 1))
-    return out.reshape(-1)[:length]
+    nb = -(-L // BLOCK)
+    q3 = _pad_axis(q, 1, nb * BLOCK).reshape(W, nb, BLOCK)
+    s3 = jnp.broadcast_to(scale[:, None, None], (W, nb, 1))
+    return _decode_call(_fp8_dequant, q3, s3, interpret)[:length]
 
 
 # ---------------------------------------------------------------------------
@@ -332,15 +306,27 @@ def fp8_decode_reduce(comp, length: int, *, interpret: bool = True):
 class CodecLowering:
     """The fused entry points for one codec's wire form.
 
+    ``encode(x2d[, err], *, interpret)`` and ``decode(comp, length, *,
+    interpret)`` are the jitted kernels; the methods bind ``interpret``
+    from the backend (``kernels.ops.interpret``):
+
     encode_feedback(x2d, err) -> (comp, new_err)   one pass over x + err
     encode_residual(x2d)      -> (comp, residual)  one pass over x
     decode_reduce(comp, L)    -> (L,) f32          one pass over the wire
     """
 
     name: str
-    encode_feedback: Callable
-    encode_residual: Callable
-    decode_reduce: Callable
+    encode: Callable
+    decode: Callable
+
+    def encode_feedback(self, x2d, err):
+        return self.encode(x2d, err, interpret=_interpret())
+
+    def encode_residual(self, x2d):
+        return self.encode(x2d, interpret=_interpret())
+
+    def decode_reduce(self, comp, length: int):
+        return self.decode(comp, length, interpret=_interpret())
 
 
 LOWERINGS: Dict[str, CodecLowering] = {}
@@ -351,26 +337,10 @@ def _register(lw: CodecLowering) -> CodecLowering:
     return lw
 
 
-def _dispatch(fn):
-    """Bind the backend choice (compiled TPU vs interpret) at call time."""
-    def call(*args, **kw):
-        return fn(*args, interpret=_interpret(), **kw)
-    return call
-
-
-_register(CodecLowering("int8_block",
-                        _dispatch(int8_encode_feedback),
-                        _dispatch(int8_encode_residual),
-                        _dispatch(int8_decode_reduce)))
-_register(CodecLowering("int4_block",
-                        _dispatch(int4_encode_feedback),
-                        _dispatch(int4_encode_residual),
-                        _dispatch(int4_decode_reduce)))
+_register(CodecLowering("int8_block", int8_encode, int8_decode_reduce))
+_register(CodecLowering("int4_block", int4_encode, int4_decode_reduce))
 if _HAVE_FP8:
-    _register(CodecLowering("fp8_sim",
-                            _dispatch(fp8_encode_feedback),
-                            _dispatch(fp8_encode_residual),
-                            _dispatch(fp8_decode_reduce)))
+    _register(CodecLowering("fp8_sim", fp8_encode, fp8_decode_reduce))
 
 
 def lowering(name: str) -> Optional[CodecLowering]:

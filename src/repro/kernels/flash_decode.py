@@ -58,7 +58,7 @@ def _kernel(idx_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def flash_decode(q, k, v, cur_index, *, chunk: int = 512,
-                 interpret: bool = True):
+                 interpret: bool):
     """q: (B,1,H,hd); k,v: (B,S,KV,hd); positions < cur_index are valid.
     Returns (B,1,H*hd) fp32."""
     B, _, H, hd = q.shape

@@ -49,7 +49,7 @@ def _kernel(dt_ref, a_ref, b_ref, c_ref, x_ref, y_ref, hT_ref, h_ref,
 
 @functools.partial(jax.jit, static_argnames=("chunk", "dblk", "interpret"))
 def mamba_scan(dt, A, Bm, Cm, x, *, chunk: int = 128, dblk: int = 256,
-               interpret: bool = True):
+               interpret: bool):
     """dt,x: (B,T,Di); A: (Di,N); Bm,Cm: (B,T,N).
     Returns y (B,T,Di) fp32, hT (B,Di,N) fp32."""
     B, T, Di = dt.shape

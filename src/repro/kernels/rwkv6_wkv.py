@@ -48,7 +48,7 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sT_ref, s_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def rwkv6_wkv(r, k, v, w, u, s0, *, chunk: int = 128, interpret: bool = True):
+def rwkv6_wkv(r, k, v, w, u, s0, *, chunk: int = 128, interpret: bool):
     """r,k,v,w: (B,T,H,hd) (w = decay in (0,1), fp32-safe); u: (H,hd);
     s0: (B,H,hd,hd). Returns y (B,T,H,hd) fp32, sT (B,H,hd,hd) fp32."""
     B, T, H, hd = r.shape

@@ -31,7 +31,7 @@ def _shift_kernel(shift_ref, src_ref, o_ref, *, n_blocks: int):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def shift_blocks(v, shift, *, interpret: bool = True):
+def shift_blocks(v, shift, *, interpret: bool):
     """v: (N, m) (block-major gather buffer); returns roll(v, shift, 0)."""
     N = v.shape[0]
     m = math.prod(v.shape[1:]) or 1
@@ -57,7 +57,7 @@ def _pack_kernel(idx_ref, src_ref, o_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def pack_blocks(src, idx, *, interpret: bool = True):
+def pack_blocks(src, idx, *, interpret: bool):
     """src: (N, m); idx: (K,) int32 — returns src[idx] as a fused gather."""
     N = src.shape[0]
     m = math.prod(src.shape[1:]) or 1

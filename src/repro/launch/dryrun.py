@@ -67,7 +67,8 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     chips = mesh.devices.size
     mf = terms_lib.model_flops(cfg, shape)
     mfa = terms_lib.model_flops_attn(cfg, shape)
-    link_bw = terms_lib.DCN_BW if multi_pod else terms_lib.ICI_BW
+    link_bw = (terms_lib.DCN_BW if multi_pod
+               else terms_lib.peaks(terms_lib.V5E).ici_bw)
     terms = terms_lib.compute_terms(costs.flops, costs.memory_bytes,
                                     costs.collective_bytes, chips, mf + mfa,
                                     costs.collective_counts, link_bw)

@@ -7,18 +7,26 @@ to O(100) pods — nothing in the sharding is pod-count-specific).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
+from repro.roofline import terms
 from repro.sharding.rules import Rules
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
-def make_mesh(shape, axes):
-    return jax.make_mesh(tuple(shape), tuple(axes))
+def make_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``: the sharding rules,
+    ``shard_map`` bodies and gathers here are written for GSPMD-propagated
+    shardings, not for ``make_mesh``'s default ``Explicit`` axes."""
+    axes = tuple(axes)
+    return jax.make_mesh(tuple(shape), axes,
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_process_mesh(node_axis: str = "node", local_axis: str = "local"):
@@ -46,7 +54,7 @@ def make_process_mesh(node_axis: str = "node", local_axis: str = "local"):
     return jax.sharding.Mesh(arr, (node_axis, local_axis))
 
 
-HBM_BYTES = 16e9  # v5e per-chip
+HBM_BYTES = terms.peaks(terms.V5E).hbm_bytes  # the production mesh is v5e
 
 
 def default_rules(mesh, kind: str, global_batch: int, seq_len: int,

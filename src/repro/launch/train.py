@@ -22,6 +22,7 @@ import numpy as np
 from repro.configs import get_config, reduced_config
 from repro.checkpoint.manager import CheckpointManager
 from repro.data.pipeline import SyntheticLM
+from repro.launch import cache as compile_cache
 from repro.models import decoder, encdec
 from repro.models.decoder import RunFlags
 from repro.optim import adamw
@@ -46,6 +47,7 @@ def main(argv=None):
                     help="failure injection: hard-exit at this step")
     ap.add_argument("--straggler-factor", type=float, default=3.0)
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     cfg = reduced_config(args.arch) if args.reduced else get_config(args.arch)
     ocfg = adamw.AdamWConfig(lr=args.lr, warmup_steps=20,

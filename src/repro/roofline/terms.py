@@ -4,8 +4,8 @@
   memory term     = HLO_bytes / (chips * HBM_bw)
   collective term = collective_bytes / (chips * link_bw)
 
-Hardware constants (TPU v5e target, per assignment):
-  197 TFLOP/s bf16 per chip, 819 GB/s HBM, ~50 GB/s/link ICI.
+Hardware constants come from :data:`PEAKS`, one row per ``device_kind``;
+a kind that is not in the table is an error, not a default.
 HLO_FLOPs/bytes come from the trip-count-weighted HLO analysis (hlo.py) of
 the post-SPMD compiled module; both are PER-DEVICE quantities, so `chips`
 does not divide them again — the formulas below therefore use per-chip
@@ -16,10 +16,41 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, Optional
 
-PEAK_FLOPS = 197e12        # bf16 / chip
-HBM_BW = 819e9             # bytes/s / chip
-ICI_BW = 50e9              # bytes/s / link (intra-pod)
-DCN_BW = 25e9              # bytes/s / host (pod axis)
+
+@dataclasses.dataclass(frozen=True)
+class Peaks:
+    """Published per-chip peaks of one accelerator kind."""
+
+    flops_bf16: float   # FLOP/s
+    hbm_bytes: float    # bytes
+    hbm_bw: float       # bytes/s
+    ici_bw: float       # bytes/s per chip-to-chip link
+    source: str
+
+
+#: ``jax.Device.device_kind`` -> published peaks. TPU v5e: 197 TFLOP/s
+#: bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of interconnect over 4 links
+#: (50 GB/s each).
+PEAKS: Dict[str, Peaks] = {
+    "TPU v5 lite": Peaks(197e12, 16e9, 819e9, 50e9,
+                         source='Google Cloud documentation, "TPU v5e"'),
+}
+
+#: The kind the production mesh, the dry run and the analytic codec
+#: rooflines model.
+V5E = "TPU v5 lite"
+
+DCN_BW = 25e9              # bytes/s / host (pod axis); modeled, no chip row
+
+
+def peaks(device_kind: str) -> Peaks:
+    """The peaks row for ``device_kind``; raises on a kind not in
+    :data:`PEAKS`."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; known: {sorted(PEAKS)}") from None
 
 
 @dataclasses.dataclass
@@ -50,10 +81,11 @@ def compute_terms(hlo_flops_per_dev: float, hlo_bytes_per_dev: float,
                   collective_bytes_per_dev: float, chips: int,
                   model_flops_global: float,
                   collective_counts: Optional[Dict[str, int]] = None,
-                  link_bw: float = ICI_BW) -> RooflineTerms:
-    compute_s = hlo_flops_per_dev / PEAK_FLOPS
-    memory_s = hlo_bytes_per_dev / HBM_BW
-    coll_s = collective_bytes_per_dev / link_bw
+                  link_bw: Optional[float] = None) -> RooflineTerms:
+    pk = peaks(V5E)
+    compute_s = hlo_flops_per_dev / pk.flops_bf16
+    memory_s = hlo_bytes_per_dev / pk.hbm_bw
+    coll_s = collective_bytes_per_dev / (link_bw or pk.ici_bw)
     useful = (model_flops_global / (hlo_flops_per_dev * chips)
               if hlo_flops_per_dev else 0.0)
     terms = {"compute": compute_s, "memory": memory_s, "collective": coll_s}

@@ -23,8 +23,9 @@ import numpy as np
 from repro.core import autotune, runtime
 from repro.core.comm import Communicator
 from repro.core.topology import Topology
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((N, P), ("node", "local"))
+mesh = make_mesh((N, P), ("node", "local"))
 topo = Topology.from_mesh(mesh)
 assert topo.link_names == ("host_cpu", "host_cpu"), topo.link_names
 comm = Communicator(mesh, topo)

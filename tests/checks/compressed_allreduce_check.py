@@ -12,8 +12,9 @@ from jax.sharding import PartitionSpec as Pt
 from repro.core import compress, mcoll, runtime
 from repro.core.comm import Communicator
 from repro.core.topology import Topology
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((N, P), ("node", "local"))
+mesh = make_mesh((N, P), ("node", "local"))
 topo = Topology.from_mesh(mesh)
 comm = Communicator(mesh, topo)
 M = N * P

@@ -7,12 +7,13 @@ import jax
 from repro.configs import get_config
 from repro.configs.base import ShapeConfig
 from repro.launch import specs
+from repro.launch.mesh import make_mesh
 from repro.models.decoder import RunFlags
 from repro.roofline import hlo as H
 from repro.sharding.rules import Rules
 from repro.train.step import TrainConfig
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 rules = Rules(batch=("data",), fsdp=("data",), tp="model")
 flags = RunFlags()
 

@@ -17,6 +17,7 @@ import numpy as np
 
 from repro.configs import reduced_config
 from repro.core.topology import Topology
+from repro.launch.mesh import make_mesh
 from repro.models import decoder
 from repro.models.decoder import RunFlags
 from repro.optim import adamw
@@ -27,7 +28,7 @@ cfg = reduced_config("smollm-360m")
 ocfg = adamw.AdamWConfig(lr=1e-3, warmup_steps=0, total_steps=10,
                          schedule="constant", grad_clip=1e9)
 tcfg = TrainConfig(optimizer=ocfg, flags=RunFlags(remat="none"))
-mesh = jax.make_mesh((N, P), ("node", "local"))
+mesh = make_mesh((N, P), ("node", "local"))
 topo = Topology(N, P)
 
 key = jax.random.PRNGKey(0)

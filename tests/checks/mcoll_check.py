@@ -14,9 +14,10 @@ import numpy as np
 
 from repro.core.topology import Topology
 from repro.core import mcoll, runtime
+from repro.launch.mesh import make_mesh
 
 M = N * P
-mesh = jax.make_mesh((N, P), ("node", "local"))
+mesh = make_mesh((N, P), ("node", "local"))
 topo = Topology(N, P)
 checks = 0
 

@@ -8,6 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import reduced_config
+from repro.launch.mesh import make_mesh
 from repro.layers import moe
 from repro.sharding.rules import Rules
 
@@ -26,7 +27,7 @@ x = jax.random.normal(jax.random.PRNGKey(1), (B, S, cfg.d_model),
 y_ref, aux_ref = moe._moe_local(p, x.reshape(-1, cfg.d_model), cfg)
 y_ref = y_ref.reshape(B, S, cfg.d_model)
 
-mesh = jax.make_mesh((DP, TP), ("data", "model"))
+mesh = make_mesh((DP, TP), ("data", "model"))
 rules = Rules(batch=("data",), fsdp=(), tp="model")
 with mesh:
     y_ep, aux_vec = moe.apply(p, x, cfg, rules=rules, mesh=mesh)

@@ -87,9 +87,10 @@ def main() -> None:
     from repro.core.autotune import TuningTable
     from repro.core.comm import Communicator
     from repro.core.topology import Topology
+    from repro.launch.mesh import make_mesh
 
     assert jax.device_count() == procs * dev, jax.device_count()
-    mesh = jax.make_mesh((procs, dev), ("node", "local"))
+    mesh = make_mesh((procs, dev), ("node", "local"))
     topo = Topology.from_mesh(mesh)
     assert topo.link_names == ("host_cpu", "host_cpu"), topo.link_names
     comm = Communicator(mesh, topo)

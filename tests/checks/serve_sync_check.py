@@ -19,6 +19,7 @@ import numpy as np
 from repro.configs import reduced_config
 from repro.core import runtime
 from repro.core.topology import Topology
+from repro.launch.mesh import make_mesh
 from repro.models import decoder
 from repro.serve.engine import Engine, Request
 
@@ -29,7 +30,7 @@ prompt = np.arange(5, dtype=np.int32) + 2
 ref = Engine(params, cfg, max_batch=1, max_len=32)
 want = ref.run([Request(prompt=prompt.copy(), max_new_tokens=4)])[0]
 
-mesh = jax.make_mesh((N, P), ("node", "local"))
+mesh = make_mesh((N, P), ("node", "local"))
 topo = Topology.from_mesh(mesh)
 runtime.clear_cache()
 runtime.selection_stats().reset()

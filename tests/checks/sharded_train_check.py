@@ -7,6 +7,7 @@ import numpy as np
 
 from repro.configs import reduced_config
 from repro.launch import specs
+from repro.launch.mesh import make_mesh
 from repro.models import decoder
 from repro.models.decoder import RunFlags
 from repro.optim import adamw
@@ -15,7 +16,7 @@ from repro.train.step import TrainConfig, train_step
 from repro.configs.base import ShapeConfig
 
 cfg = reduced_config("yi-34b")
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 rules = Rules(batch=("data",), fsdp=("data",), tp="model")
 ocfg = adamw.AdamWConfig(lr=1e-3, warmup_steps=0, total_steps=5,
                          schedule="constant")

@@ -29,8 +29,9 @@ import numpy as np
 from repro.core import autotune, runtime, telemetry
 from repro.core.comm import Communicator
 from repro.core.topology import Topology
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((N, P), ("node", "local"))
+mesh = make_mesh((N, P), ("node", "local"))
 topo = Topology.from_mesh(mesh)
 comm = Communicator(mesh, topo)
 telemetry.enable()

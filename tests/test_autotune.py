@@ -9,6 +9,7 @@ import pytest
 from repro.core import autotune, compress, costmodel, mcoll
 from repro.core.autotune import Selector, TuningTable
 from repro.core.topology import Topology, derive_link
+from repro.launch.mesh import make_mesh
 
 from subproc import run_check
 
@@ -388,7 +389,7 @@ def test_net_for_defaults_and_overrides():
 
 
 def test_from_mesh_derives_host_cpu_links():
-    mesh = jax.make_mesh((1, 1), ("node", "local"))
+    mesh = make_mesh((1, 1), ("node", "local"))
     topo = Topology.from_mesh(mesh)
     assert topo.link_names == ("host_cpu", "host_cpu")
     assert derive_link(mesh, "node", "inter") == "host_cpu"

@@ -12,6 +12,7 @@ import numpy as np
 import pytest
 
 from repro.checkpoint.manager import CheckpointManager
+from repro.launch.mesh import make_mesh
 
 REPO = pathlib.Path(__file__).resolve().parent.parent
 
@@ -91,7 +92,7 @@ def test_elastic_reshard_restore(tmp_path):
     mgr = CheckpointManager(str(tmp_path))
     tree = {"w": jnp.arange(16, dtype=jnp.float32).reshape(4, 4)}
     mgr.save(1, tree)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     shardings = {"w": NamedSharding(mesh, P("data", None))}
     out = mgr.restore(1, jax.tree.map(jnp.zeros_like, tree),
                       shardings=shardings)

@@ -20,12 +20,13 @@ from repro.core import comm as comm_mod
 from repro.core import mcoll, runtime
 from repro.core.comm import Communicator, PersistentOp, PlanSpec
 from repro.core.topology import Topology
+from repro.launch.mesh import make_mesh
 
 REPO = pathlib.Path(__file__).resolve().parent.parent
 
 
 def _mesh_topo(node="node", local="local"):
-    mesh = jax.make_mesh((1, 1), (node, local))
+    mesh = make_mesh((1, 1), (node, local))
     return mesh, Topology(1, 1, node_axis=node, local_axis=local)
 
 
@@ -265,7 +266,7 @@ def test_split_color_groups():
 def test_unscoped_root_requires_split():
     """A mesh without the node/local axes yields an unscoped root:
     split(axes=...) works, collectives raise with a pointer to it."""
-    mesh = jax.make_mesh((1,), ("tp",))
+    mesh = make_mesh((1,), ("tp",))
     root = Communicator(mesh)
     assert root.topo is None
     with pytest.raises(ValueError, match=r"split\(axes=\.\.\.\)"):

@@ -27,6 +27,7 @@ from _hypothesis_compat import given, settings, strategies as st
 from repro.core import autotune, compress, costmodel, mcoll, runtime
 from repro.core.comm import Communicator
 from repro.core.topology import Topology
+from repro.launch.mesh import make_mesh
 
 # ---------------------------------------------------------------------------
 # mesh from the ambient device count (the CI matrix sets XLA_FLAGS)
@@ -36,7 +37,7 @@ DC = jax.device_count()
 P = 2 if DC % 2 == 0 else 1
 N = DC // P
 M = N * P
-mesh = jax.make_mesh((N, P), ("node", "local"))
+mesh = make_mesh((N, P), ("node", "local"))
 topo = Topology(N, P)
 COMM = Communicator(mesh, topo)
 

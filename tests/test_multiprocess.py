@@ -81,9 +81,11 @@ def test_spawn_failure_carries_rank_tails():
 
 
 class _Dev:
-    def __init__(self, platform, process_index, slice_index=None):
+    def __init__(self, platform, process_index, slice_index=None,
+                 device_kind=None):
         self.platform = platform
         self.process_index = process_index
+        self.device_kind = device_kind
         if slice_index is not None:
             self.slice_index = slice_index
 
@@ -100,9 +102,9 @@ class _FakeMesh:
                 "local": self.devices.shape[1]}
 
 
-def _mesh(platform, procs, per_proc):
-    return _FakeMesh([[_Dev(platform, p) for _ in range(per_proc)]
-                      for p in range(procs)])
+def _mesh(platform, procs, per_proc, device_kind=None):
+    return _FakeMesh([[_Dev(platform, p, device_kind=device_kind)
+                       for _ in range(per_proc)] for p in range(procs)])
 
 
 def test_derive_link_splits_on_process_boundary():
@@ -132,9 +134,16 @@ def test_derive_link_unknown_platform_warns_once():
 
 
 def test_derive_link_tpu_unchanged():
-    mesh = _mesh("tpu", 2, 2)
+    mesh = _mesh("tpu", 2, 2, device_kind="TPU v5 lite")
     assert derive_link(mesh, "node", "inter") == "tpu_v5e_dcn"
     assert derive_link(mesh, "local", "intra") == "tpu_v5e_ici"
+
+
+@pytest.mark.parametrize("kind", ["TPU v4", "TPU v6 lite", None])
+def test_derive_link_unknown_tpu_kind_raises(kind):
+    mesh = _mesh("tpu", 1, 2, device_kind=kind)
+    with pytest.raises(ValueError, match="device kind"):
+        derive_link(mesh, "local", "intra")
 
 
 # -- cross-rank table merge semantics ----------------------------------------

@@ -3,6 +3,7 @@ weighting on a real compiled scan, analytic model-FLOPs sanity."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from _hypothesis_compat import given, settings, strategies as st
 
 from repro.configs import SHAPES, get_config
@@ -121,3 +122,18 @@ def test_terms_bottleneck_classification():
     assert t.bottleneck == "memory"  # 1e12B/819GBps >> 1e12F/197TFs
     t2 = T.compute_terms(1e14, 1e10, 1e9, 256, 6e16)
     assert t2.bottleneck == "compute"
+
+
+def test_peaks_table_keyed_by_device_kind():
+    pk = T.peaks("TPU v5 lite")
+    assert (pk.flops_bf16, pk.hbm_bw, pk.hbm_bytes) == (197e12, 819e9, 16e9)
+    assert "TPU v5e" in pk.source
+    assert T.peaks(T.V5E) is pk
+    t = T.compute_terms(1e12, 0.0, 0.0, 1, 1e12)
+    np.testing.assert_allclose(t.compute_s, 1e12 / 197e12)
+
+
+@pytest.mark.parametrize("kind", ["cpu", "TPU v4", "TPU v6 lite"])
+def test_peaks_unknown_device_kind_raises(kind):
+    with pytest.raises(KeyError, match="no published peaks"):
+        T.peaks(kind)

@@ -1,4 +1,4 @@
-"""Compat shim + collective runtime: version resolution, kwarg spelling,
+"""Collective runtime: the single ``jax.shard_map``/``check_vma`` path,
 build/exec cache behavior, and the no-direct-shard_map regression grep.
 
 Cache tests run in-process on 1-device meshes (a (1, 1) node x local mesh
@@ -7,6 +7,7 @@ All cache tests drive the runtime through the Communicator (the supported
 surface, via ``_coll``); the ``runtime.collective`` deprecation shim has
 its own tests in test_comm.py.
 """
+import inspect
 import pathlib
 import re
 
@@ -17,8 +18,9 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.core import comm as comm_mod
-from repro.core import compat, runtime
+from repro.core import runtime
 from repro.core.topology import Topology
+from repro.launch.mesh import make_mesh
 
 SRC = pathlib.Path(__file__).resolve().parent.parent / "src"
 
@@ -28,47 +30,41 @@ def _coll(mesh, topo, name, algo, x, **kw):
 
 
 # ---------------------------------------------------------------------------
-# compat: implementation resolution + kwarg translation
+# shard_map: the one installed path (jax.shard_map with check_vma)
 # ---------------------------------------------------------------------------
 
 
 def test_compat_picks_installed_impl():
-    """The shim must resolve to the implementation this JAX actually has,
-    in preference order jax.shard_map > jax.sharding > experimental."""
-    if getattr(jax, "shard_map", None) is not None:
-        assert compat.SHARD_MAP_SOURCE == "jax"
-    elif getattr(jax.sharding, "shard_map", None) is not None:
-        assert compat.SHARD_MAP_SOURCE == "jax.sharding"
-    else:
-        from jax.experimental import shard_map as esm
-        assert esm.shard_map is not None
-        assert compat.SHARD_MAP_SOURCE == "jax.experimental.shard_map"
+    """``runtime.sharded`` calls the installed ``jax.shard_map`` directly;
+    no other spelling (``jax.experimental.shard_map``) is resolved."""
+    assert callable(jax.shard_map)
+    src = inspect.getsource(runtime.sharded)
+    assert "jax.shard_map(" in src
+    assert "experimental" not in src
 
 
 def test_compat_kwarg_spelling_matches_impl():
-    import inspect
-    params = inspect.signature(compat._shard_map_impl).parameters
-    if "check_vma" in params:
-        assert compat.CHECK_KW == "check_vma"
-    elif "check_rep" in params:
-        assert compat.CHECK_KW == "check_rep"
-    else:
-        assert compat.CHECK_KW is None
+    """The installed ``jax.shard_map`` takes ``check_vma`` (not the old
+    ``check_rep``), and ``sharded(check=...)`` is passed through as it."""
+    params = inspect.signature(jax.shard_map).parameters
+    assert "check_vma" in params and "check_rep" not in params
+    assert "check_vma=check" in inspect.getsource(runtime.sharded)
 
 
 def test_compat_shard_map_executes():
-    mesh = jax.make_mesh((1,), ("d",))
-    fn = compat.shard_map(lambda x: x * 2, mesh, in_specs=(P("d"),),
-                          out_specs=P("d"), check_vma=False)
+    mesh = make_mesh((1,), ("d",))
+    fn = runtime.sharded(lambda x: x * 2, mesh, in_specs=(P("d"),),
+                         out_specs=P("d"))
     np.testing.assert_array_equal(np.asarray(fn(jnp.arange(4.0))),
                                   np.arange(4.0) * 2)
-    # the check_rep alias spelling must work too
-    fn2 = compat.shard_map(lambda x: x + 1, mesh, in_specs=(P("d"),),
-                           out_specs=P("d"), check_rep=False)
+    # check=True is check_vma: a varying output declared replicated is
+    # refused at trace time; check=False lets it through
+    replicated = dict(in_specs=(P("d"),), out_specs=P())
+    fn2 = runtime.sharded(lambda x: x + 1, mesh, **replicated)
     np.testing.assert_array_equal(np.asarray(fn2(jnp.zeros(2))), np.ones(2))
-    with pytest.raises(TypeError):
-        compat.shard_map(lambda x: x, mesh, in_specs=(P("d"),),
-                         out_specs=P("d"), check_vma=False, check_rep=False)
+    with pytest.raises(ValueError, match="replication"):
+        runtime.sharded(lambda x: x + 1, mesh, check=True,
+                        **replicated)(jnp.zeros(2))
 
 
 # ---------------------------------------------------------------------------
@@ -77,7 +73,7 @@ def test_compat_shard_map_executes():
 
 
 def _mesh_topo(node="node", local="local"):
-    mesh = jax.make_mesh((1, 1), (node, local))
+    mesh = make_mesh((1, 1), (node, local))
     return mesh, Topology(1, 1, node_axis=node, local_axis=local)
 
 
@@ -468,21 +464,25 @@ def test_shrinking_limit_evicts_immediately():
 
 
 # ---------------------------------------------------------------------------
-# regression: compat.py is the only module touching the raw API
+# regression: runtime.sharded is the only code touching the raw API
 # ---------------------------------------------------------------------------
 
 
 def test_no_direct_shard_map_outside_compat():
+    """``jax.shard_map`` is named only in ``core/runtime.py``, and called
+    there exactly once (``runtime.sharded``)."""
     pattern = re.compile(
         r"jax\.shard_map|jax\.sharding\.shard_map"
         r"|experimental\.shard_map|experimental import shard_map")
+    home = SRC / "repro" / "core" / "runtime.py"
     offenders = []
     for path in sorted(SRC.rglob("*.py")):
-        if path.name == "compat.py":
+        if path == home:
             continue
         for i, line in enumerate(path.read_text().splitlines(), 1):
             if pattern.search(line):
                 offenders.append(f"{path.relative_to(SRC)}:{i}: {line.strip()}")
     assert not offenders, (
-        "direct shard_map references outside compat.py:\n"
+        "direct shard_map references outside core/runtime.py:\n"
         + "\n".join(offenders))
+    assert home.read_text().count("jax.shard_map(") == 1

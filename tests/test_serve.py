@@ -5,6 +5,7 @@ import pytest
 
 from repro.configs import reduced_config
 from repro.data.pipeline import SyntheticLM
+from repro.launch.mesh import make_mesh
 from repro.models import decoder
 from repro.serve.engine import Engine, Request
 
@@ -61,7 +62,7 @@ def test_engine_degenerate_mesh_skips_sync_dispatch():
     ref = Engine(params, cfg, max_batch=1, max_len=32)
     want = ref.run([Request(prompt=prompt.copy(), max_new_tokens=4)])[0]
 
-    mesh = jax.make_mesh((1, 1), ("node", "local"))
+    mesh = make_mesh((1, 1), ("node", "local"))
     topo = Topology.from_mesh(mesh)
     runtime.clear_cache()
     eng = Engine(params, cfg, max_batch=1, max_len=32, mesh=mesh, topo=topo)
@@ -106,7 +107,7 @@ def test_engine_unscoped_root_mesh_raises_at_construction():
     broadcast_init on the first multi-replica tick."""
     cfg = reduced_config("smollm-360m")
     params = decoder.init(jax.random.PRNGKey(0), cfg)
-    mesh = jax.make_mesh((1, 1, 1), ("dp", "tp", "ep"))
+    mesh = make_mesh((1, 1, 1), ("dp", "tp", "ep"))
     with pytest.raises(ValueError, match=r"sync_axes"):
         Engine(params, cfg, max_batch=1, max_len=32, mesh=mesh)
     # the error's own guidance works: scoping the sync via sync_axes=

@@ -16,6 +16,7 @@ import pytest
 from repro.core import autotune, runtime, telemetry
 from repro.core.comm import Communicator
 from repro.core.topology import Topology
+from repro.launch.mesh import make_mesh
 from subproc import run_check
 
 
@@ -30,7 +31,7 @@ def _telemetry_off():
 
 
 def _mesh_topo():
-    mesh = jax.make_mesh((1, 1), ("node", "local"))
+    mesh = make_mesh((1, 1), ("node", "local"))
     return mesh, Topology(1, 1)
 
 
