@@ -577,8 +577,9 @@ def codec_kernel_mode(out_path=None):
     compress.jnp_reference_paths() so its compiled program never routes a
     kernel), then report the ANALYTIC memory traffic per stage
     (kernels.codec.memory_traffic — the HBM passes each path makes) and
-    the roofline seconds those bytes cost at v5e's HBM bandwidth
-    (``roofline.terms.PEAKS``). On CPU the fused
+    the roofline seconds those bytes cost at the HBM bandwidth of the TPU
+    it runs on (``roofline.terms.PEAKS``; an unknown kind raises), or of a
+    modeled v5e on the CPU, which has no peaks row. On CPU the fused
     kernels run in interpret mode, so wall-clock favors jnp — the traffic
     model is the TPU-relevant number, and the acceptance bar (fused moves
     <= half the jnp bytes on at least one codec) is asserted here.
@@ -591,7 +592,9 @@ def codec_kernel_mode(out_path=None):
     from repro.kernels import codec as ckern
     from repro.roofline import terms
 
-    hbm_bw = terms.peaks(terms.V5E).hbm_bw  # analytic: modeled on v5e
+    kind = (jax.devices()[0].device_kind if jax.default_backend() == "tpu"
+            else terms.V5E)
+    hbm_bw = terms.peaks(kind).hbm_bw
     S, W = 8, 8
     L = 16 * compress.BLOCK          # 4096 elems/slice, 32 KiB wire payload
     n_elems = S * L
@@ -616,7 +619,9 @@ def codec_kernel_mode(out_path=None):
         np.testing.assert_allclose(np.asarray(out_f), np.asarray(out_j),
                                    rtol=1e-6, atol=1e-5 * W)
         wb_per_elem = cd.wire_bytes(comp_f) / float(n_elems)
-        traffic = ckern.memory_traffic(wb_per_elem, n_elems, W=W)
+        traffic = ckern.memory_traffic(
+            wb_per_elem, n_elems, W=W,
+            amax_pass=ckern.lowering(name).amax_pass)
         row = {"codec": name, "elems": n_elems,
                "wire_bytes_per_elem": wb_per_elem,
                "wall_us": {"encode_feedback": {"fused": us_f_ef,
@@ -660,7 +665,7 @@ def codec_kernel_mode(out_path=None):
                "slices": S, "world": W, "elems_per_slice": L,
                "fused_codecs": list(compress.fused_codecs()),
                "rows": rows, "traffic_halved": halved,
-               "zlib_sim": zlib_row,
+               "zlib_sim": zlib_row, "roofline_device_kind": kind,
                "note": "wall_us on CPU runs the kernels in interpret mode; "
                        "traffic/roofline_s are the analytic HBM passes"}
     if out_path:

@@ -5,14 +5,17 @@
           Communicator on a (1, 1) ("node", "local") mesh at 16 B, 512 B
           and a 4 MiB gradient bucket, bitwise against the collective's
           xla entry, plus one persistent allreduce init -> start -> wait;
-      (b) the fused codec kernels, compiled, on a 4 MiB f32 bucket against
-          the jnp reference paths within the codec's collective tolerance;
+      (b) the fused codec kernels, compiled, on a 4 MiB f32 bucket and on
+          a shape whose rows end in a partial tile, against the jnp
+          reference paths within the codec's collective tolerance;
       (c) smollm-360m at full width: steps through the overlapped
           data-parallel train step and through ``repro.launch.train``;
       (d) serving the same model through ``serve.Engine``.
   python chip_smoke.py --chips 4   four chips, one process, and nothing
       else: (a) on a (2, 2) mesh, a compressed allreduce per fused codec,
-      and one data-parallel smollm-360m step against the single-jit step.
+      and one data-parallel smollm-360m step against the single-jit step,
+      compared on the synced gradient (Adam's new first moment) as well
+      as the loss and the updated params.
 
 Progress and set-up times (compilation included; they are not speed) go
 to stdout. The last line is one JSON object naming the device:
@@ -34,6 +37,8 @@ sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent / "src"))
 ARCH = "smollm-360m"
 SIZES = (16, 512, 4 << 20)          # collective payload bytes per device
 BUCKET = 4 << 20                    # the gradient bucket of (a) and (b)
+CODEC_SLICES = 4                    # (b): the bucket as 4 wire slices
+CODEC_EDGE = (3, 46000)             # (b): 540 block rows, a partial tile
 TRAIN_STEPS, TRAIN_BATCH, TRAIN_SEQ, TRAIN_LR = 5, 4, 512, 3e-3
 TRAIN_BUCKET_BYTES = 128 << 20      # overlapped-step segments (~3 layers)
 LOSS_RTOL = 5e-3                    # step-0 loss, overlapped vs single jit
@@ -41,6 +46,10 @@ SERVE_PROMPTS = (16, 40) * 4        # 8 requests of mixed prompt length
 SERVE_NEW, SERVE_BATCH, SERVE_MAX_LEN = 16, 8, 64
 SERVE_TIE_ULPS = 4                  # engine vs recompute logit near-tie
 DP_LR = 1e-3                        # four-chip step: constant lr, no warmup
+# per-leaf |m_dp - m_jit| / |m_jit|: 5x the v5e reading (2.47e-2 on
+# attn.wq; 2 layers at d_model 128 on the CPU read 1.8e-3), 8x under the
+# weakest planted fault (0.978)
+DP_GRAD_RTOL = 2.0 ** -3
 
 
 def log(msg: str) -> None:
@@ -69,7 +78,7 @@ def peak_bytes(devices):
 # ---------------------------------------------------------------------------
 
 
-def phase_collectives(mesh, sizes=SIZES):
+def phase_collectives(mesh):
     import numpy as np
     from repro.core import autotune, mcoll, runtime
     from repro.core.comm import Communicator
@@ -81,7 +90,7 @@ def phase_collectives(mesh, sizes=SIZES):
     checked, infeasible = 0, []
     for name in runtime.collectives():
         feasible = autotune.candidates(name, topo)
-        for nbytes in sizes:
+        for nbytes in SIZES:
             x = runtime.example_input(name, topo, nbytes, devices=n_dev)
             # scatter registers no xla entry: its result is the operand
             want = (np.asarray(comm.invoke(name, x, algo="xla"))
@@ -133,66 +142,73 @@ def phase_compressed_allreduce(mesh):
 # ---------------------------------------------------------------------------
 
 
-def phase_codecs(slices: int = 4):
+def phase_codecs():
+    """Every fused codec on the 4 MiB bucket as CODEC_SLICES wire slices,
+    and on CODEC_EDGE, whose rows end in a partial tile."""
     import jax
     import numpy as np
     from repro.core import compress
     from repro.kernels import codec as ckern
     from repro.kernels import ops
 
-    L = BUCKET // 4 // slices
-    k1, k2 = jax.random.split(jax.random.PRNGKey(0))
-    x = jax.random.normal(k1, (slices, L))
-    err = jax.random.normal(k2, (slices, L)) * 0.01
-    A = float(np.abs(np.asarray(x + err)).max())
     out = {"interpret": ops.interpret()}
-    for name in ckern.fused_codec_names():
-        cd = compress.codec(name)
-        lw = ckern.lowering(name)
-
-        def paths():
-            comp, res = jax.jit(cd.encode_with_feedback)(x, err)
-            comp_r, res_r = jax.jit(cd.encode_residual)(x)
-            red = jax.jit(lambda c: cd.decode_reduce(c, L))(comp)
-            return comp, res, res_r, red, comp_r
-
-        def ef_gap(p):
-            """How far each residual is from payload - decode(its wire),
-            the error-feedback invariant, with every op run on its own."""
-            return max(float(np.abs(np.asarray(r - (c - cd.decode(w, L))))
-                             .max()) for r, c, w in ((p[1], x + err, p[0]),
-                                                     (p[2], x, p[4])))
-
-        got = paths()
-        with compress.jnp_reference_paths():
-            ref = paths()
-        # two valid encodings each decode within eps*A of the payload
-        tol = 2 * compress.collective_tolerance(name, "allgather", 1, A)
-        red_tol = compress.collective_tolerance(name, "reduce_scatter",
-                                                slices, A)
-        dec = [np.asarray(cd.decode(p[0], L)) for p in (got, ref)]
-        errs = {"decode": float(np.abs(dec[0] - dec[1]).max()),
-                "feedback": float(np.abs(np.asarray(got[1] - ref[1])).max()),
-                "residual": float(np.abs(np.asarray(got[2] - ref[2])).max()),
-                "decode_reduce": float(
-                    np.abs(np.asarray(got[3] - ref[3])).max())}
-        bitwise = all(np.array_equal(np.asarray(a), np.asarray(b))
-                      for a, b in zip(jax.tree.leaves(got[0]),
-                                      jax.tree.leaves(ref[0])))
-        for k, v in errs.items():
-            if not v <= (red_tol if k == "decode_reduce" else tol):
-                raise AssertionError(f"{name} {k}: {v} over tolerance")
-        # the kernels' residuals hold to a few f32 ulps of the payload
-        gap, gap_tol = ef_gap(got), 2.0 ** -20 * A
-        if not gap <= gap_tol:
-            raise AssertionError(f"{name}: fused residual is {gap} from "
-                                 f"payload - decode(wire) (> {gap_tol})")
-        if lw is None or got[3].shape != (L,):
-            raise AssertionError(f"{name}: no fused lowering ran")
-        out[name] = dict(errs, wire_bitwise=bitwise, tol=tol,
-                         reduce_tol=red_tol, ef_gap=gap,
-                         jnp_ef_gap=ef_gap(ref))
+    for S, L in ((CODEC_SLICES, BUCKET // 4 // CODEC_SLICES), CODEC_EDGE):
+        k1, k2 = jax.random.split(jax.random.PRNGKey(L))
+        x = jax.random.normal(k1, (S, L))
+        err = jax.random.normal(k2, (S, L)) * 0.01
+        A = float(np.abs(np.asarray(x + err)).max())
+        for name in ckern.fused_codec_names():
+            out[f"{name}/{S}x{L}"] = _codec_check(name, x, err, A)
     return out
+
+
+def _codec_check(name, x, err, A):
+    import jax
+    import numpy as np
+    from repro.core import compress
+    from repro.kernels import codec as ckern
+
+    S, L = x.shape
+    cd = compress.codec(name)
+
+    def paths():
+        comp, res = jax.jit(cd.encode_with_feedback)(x, err)
+        comp_r, res_r = jax.jit(cd.encode_residual)(x)
+        red = jax.jit(lambda c: cd.decode_reduce(c, L))(comp)
+        return comp, res, res_r, red, comp_r
+
+    def ef_gap(p):
+        """How far each residual is from payload - decode(its wire), the
+        error-feedback invariant, with every op run on its own."""
+        return max(float(np.abs(np.asarray(r - (c - cd.decode(w, L)))).max())
+                   for r, c, w in ((p[1], x + err, p[0]), (p[2], x, p[4])))
+
+    got = paths()
+    with compress.jnp_reference_paths():
+        ref = paths()
+    # two valid encodings each decode within eps*A of the payload
+    tol = 2 * compress.collective_tolerance(name, "allgather", 1, A)
+    red_tol = compress.collective_tolerance(name, "reduce_scatter", S, A)
+    dec = [np.asarray(cd.decode(p[0], L)) for p in (got, ref)]
+    errs = {"decode": float(np.abs(dec[0] - dec[1]).max()),
+            "feedback": float(np.abs(np.asarray(got[1] - ref[1])).max()),
+            "residual": float(np.abs(np.asarray(got[2] - ref[2])).max()),
+            "decode_reduce": float(np.abs(np.asarray(got[3] - ref[3])).max())}
+    bitwise = all(np.array_equal(np.asarray(a), np.asarray(b))
+                  for a, b in zip(jax.tree.leaves(got[0]),
+                                  jax.tree.leaves(ref[0])))
+    for k, v in errs.items():
+        if not v <= (red_tol if k == "decode_reduce" else tol):
+            raise AssertionError(f"{name} {S}x{L} {k}: {v} over tolerance")
+    # the kernels' residuals hold to a few f32 ulps of the payload
+    gap, gap_tol = ef_gap(got), 2.0 ** -20 * A
+    if not gap <= gap_tol:
+        raise AssertionError(f"{name} {S}x{L}: fused residual is {gap} "
+                             f"from payload - decode(wire) (> {gap_tol})")
+    if ckern.lowering(name) is None or got[3].shape != (L,):
+        raise AssertionError(f"{name}: no fused lowering ran")
+    return dict(errs, wire_bitwise=bitwise, tol=tol, reduce_tol=red_tol,
+                ef_gap=gap, jnp_ef_gap=ef_gap(ref))
 
 
 # ---------------------------------------------------------------------------
@@ -208,9 +224,7 @@ def _losses_ok(name, losses):
         raise AssertionError(f"{name}: loss did not fall {losses}")
 
 
-def phase_train(mesh, cfg, *, steps=TRAIN_STEPS, batch=TRAIN_BATCH,
-                seq=TRAIN_SEQ, lr=TRAIN_LR, bucket_bytes=TRAIN_BUCKET_BYTES,
-                train_args=()):
+def phase_train(mesh, cfg):
     """The overlapped step and ``repro.launch.train.main`` on the same
     model, data and optimizer settings (``launch.train``'s own)."""
     import gc
@@ -227,7 +241,8 @@ def phase_train(mesh, cfg, *, steps=TRAIN_STEPS, batch=TRAIN_BATCH,
     from repro.train import manual_step
     from repro.train.step import TrainConfig
 
-    ocfg = adamw.AdamWConfig(lr=lr, warmup_steps=20, total_steps=steps)
+    ocfg = adamw.AdamWConfig(lr=TRAIN_LR, warmup_steps=20,
+                             total_steps=TRAIN_STEPS)
     tcfg = TrainConfig(optimizer=ocfg, flags=RunFlags(remat="none"))
     # placed as the step's outputs are, so step 1 reuses step 0's programs
     rep = NamedSharding(mesh, P())
@@ -235,10 +250,11 @@ def phase_train(mesh, cfg, *, steps=TRAIN_STEPS, batch=TRAIN_BATCH,
     params = jax.device_put(decoder.init(jax.random.PRNGKey(0), cfg), rep)
     opt = jax.device_put(adamw.init(params, ocfg), rep)
     step = manual_step.make_overlapped_train_step(
-        cfg, tcfg, mesh, Topology.from_mesh(mesh), bucket_bytes=bucket_bytes)
-    data = SyntheticLM(cfg.vocab, seq, batch)
+        cfg, tcfg, mesh, Topology.from_mesh(mesh),
+        bucket_bytes=TRAIN_BUCKET_BYTES)
+    data = SyntheticLM(cfg.vocab, TRAIN_SEQ, TRAIN_BATCH)
     overlapped, times, compiles = [], [], []
-    for s in range(steps):
+    for s in range(TRAIN_STEPS):
         b = jax.device_put(data.batch(s), dat)
         n0 = compile_cache.stats()["requests"]
         t0 = time.perf_counter()
@@ -250,9 +266,9 @@ def phase_train(mesh, cfg, *, steps=TRAIN_STEPS, batch=TRAIN_BATCH,
     del params, opt, step, m
     gc.collect()
     single = launch_train.main(
-        ["--arch", cfg.name, "--steps", str(steps), "--batch", str(batch),
-         "--seq", str(seq), "--lr", str(lr), "--log-every", "1",
-         *train_args])
+        ["--arch", cfg.name, "--steps", str(TRAIN_STEPS),
+         "--batch", str(TRAIN_BATCH), "--seq", str(TRAIN_SEQ),
+         "--lr", str(TRAIN_LR), "--log-every", "1"])
     _losses_ok("overlapped", overlapped)
     _losses_ok("launch.train", single)
     rel = abs(overlapped[0] - single[0]) / abs(single[0])
@@ -260,15 +276,51 @@ def phase_train(mesh, cfg, *, steps=TRAIN_STEPS, batch=TRAIN_BATCH,
         raise AssertionError(f"step-0 losses differ by {rel:.2e} relative "
                              f"(> {LOSS_RTOL}): {overlapped[0]} vs "
                              f"{single[0]}")
-    return {"batch": batch, "seq": seq, "mode": mode, "segments": segments,
+    return {"batch": TRAIN_BATCH, "seq": TRAIN_SEQ, "mode": mode,
+            "segments": segments,
             "overlapped_losses": overlapped, "single_jit_losses": single,
             "step0_rel_diff": rel, "overlapped_step_s": times,
             "overlapped_compiles": compiles}
 
 
+def _grad_rel(m, ref):
+    """Per-leaf L2 ``|m - ref| / |ref|``: the largest, and its leaf."""
+    import jax
+    import jax.numpy as jnp
+
+    rel = jax.tree_util.tree_map_with_path(
+        lambda path, a, b: (jax.tree_util.keystr(path), float(
+            jnp.linalg.norm(a - b) / jnp.maximum(jnp.linalg.norm(b), 1e-30))),
+        m, ref)
+    leaf, worst = max(jax.tree.leaves(rel, is_leaf=lambda t: isinstance(
+        t, tuple)), key=lambda t: t[1])
+    return worst, leaf
+
+
+def _grad_rel_by_layer(m, ref):
+    """For each layer of the stacked blocks, the largest relative L2 gap
+    over the leaves under ``groups``."""
+    import jax
+    import jax.numpy as jnp
+
+    def per_layer(a, b):
+        axes = tuple(range(1, a.ndim))
+        return (jnp.sqrt(jnp.sum((a - b) ** 2, axes))
+                / jnp.maximum(jnp.sqrt(jnp.sum(b ** 2, axes)), 1e-30))
+
+    rel = jnp.stack([per_layer(a, b) for a, b in zip(
+        jax.tree.leaves(m["groups"]), jax.tree.leaves(ref["groups"]))])
+    return [float(v) for v in rel.max(axis=0)]
+
+
 def phase_dp_step(mesh, cfg):
     """One data-parallel overlapped step against the single-jit step on
-    the same mesh: the same loss and updated params within tolerance."""
+    the same mesh: the same loss, updated params within Adam's step, and
+    the same synced gradient. The gradient is read off Adam's new first
+    moment, ``(1 - b1) * g`` at the first step with clipping off; each
+    leaf must agree within DP_GRAD_RTOL relative norm. The gradients a
+    broken sync would give (chip 0's shard alone, half the batch) are run
+    through the single-jit step and must fail that check."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -282,48 +334,72 @@ def phase_dp_step(mesh, cfg):
     from repro.train.step import TrainConfig, train_step
 
     ocfg = adamw.AdamWConfig(lr=DP_LR, warmup_steps=0, total_steps=1,
-                             schedule="constant")
+                             schedule="constant", grad_clip=1e9)
     tcfg = TrainConfig(optimizer=ocfg, flags=RunFlags(remat="none"))
     rep = NamedSharding(mesh, P())
     dat = NamedSharding(mesh, P(tuple(mesh.axis_names)))
     world = mesh.devices.size
+    rows = 2 * world
     # built in place on every chip, so nothing sits on chip 0 alone
     init = jax.jit(lambda k: decoder.init(k, cfg), out_shardings=rep)
     init_opt = jax.jit(lambda p: adamw.init(p, ocfg), out_shardings=rep)
-    data = SyntheticLM(cfg.vocab, TRAIN_SEQ, 2 * world).batch(0)
-    batch = {k: jax.device_put(v, dat) for k, v in data.items()}
+    data = SyntheticLM(cfg.vocab, TRAIN_SEQ, rows).batch(0)
 
-    params = init(jax.random.PRNGKey(0))
-    p0 = jax.tree.map(lambda a: float(jnp.abs(a.astype(jnp.float32)).max()),
-                      params)
-    step = manual_step.make_overlapped_train_step(
-        cfg, tcfg, mesh, Topology.from_mesh(mesh),
-        bucket_bytes=TRAIN_BUCKET_BYTES)
-    ov_p, _, ov_m = step(params, init_opt(params), batch)
-    ov_loss = float(ov_m["loss"])
-    del step, params
+    def place(n):
+        """The batch's first ``n`` rows, repeated to the full batch."""
+        return {k: jax.device_put(np.concatenate([v[:n]] * (rows // n)), dat)
+                for k, v in data.items()}
 
     jit_step = jax.jit(lambda p, o, b: train_step(p, o, b, cfg, tcfg),
                        in_shardings=(rep, rep, dat),
                        out_shardings=(rep, rep, None), donate_argnums=(1,))
     params = init(jax.random.PRNGKey(0))
-    jit_p, _, jit_m = jit_step(params, init_opt(params), batch)
-    jit_loss = float(jit_m["loss"])
+    p0 = jax.tree.map(lambda a: float(jnp.abs(a.astype(jnp.float32)).max()),
+                      params)
+    jit_p, jit_o, jit_m = jit_step(params, init_opt(params), place(rows))
+    jit_loss, ref_m = float(jit_m["loss"]), jit_o["m"]
+    del jit_o
+
+    step = manual_step.make_overlapped_train_step(
+        cfg, tcfg, mesh, Topology.from_mesh(mesh),
+        bucket_bytes=TRAIN_BUCKET_BYTES)
+    ov_p, ov_o, ov_m = step(params, init_opt(params), place(rows))
+    ov_loss = float(ov_m["loss"])
+    grad_rel, grad_leaf = _grad_rel(ov_o["m"], ref_m)
+    by_layer = _grad_rel_by_layer(ov_o["m"], ref_m)
+    del step, ov_o, params
     rel = abs(ov_loss - jit_loss) / abs(jit_loss)
     if not (np.isfinite(ov_loss) and rel <= LOSS_RTOL):
         raise AssertionError(f"DP loss {ov_loss} vs single jit {jit_loss}")
+    if not grad_rel <= DP_GRAD_RTOL:
+        raise AssertionError(f"synced gradient differs: {grad_leaf} at "
+                             f"{grad_rel:.3e} relative (> {DP_GRAD_RTOL})")
     # Adam moves an element by at most ~lr, so two correct updates differ
     # by <= 2 lr plus half a bf16 ulp of the leaf's magnitude
     leaf_tol = [2 * DP_LR + 2.0 ** -8 * m for m in jax.tree.leaves(p0)]
     diffs = [float(jnp.abs(a.astype(jnp.float32)
                            - b.astype(jnp.float32)).max())
              for a, b in zip(jax.tree.leaves(ov_p), jax.tree.leaves(jit_p))]
+    del ov_p, jit_p
     bad = [(d, t) for d, t in zip(diffs, leaf_tol) if not d <= t]
     if bad:
         raise AssertionError(f"updated params differ: {bad[:4]}")
+    faults = {}
+    for name, n in (("chip0_shard_only", rows // world),
+                    ("half_batch", rows // 2)):
+        params = init(jax.random.PRNGKey(0))
+        _, fo, _ = jit_step(params, init_opt(params), place(n))
+        faults[name] = _grad_rel(fo["m"], ref_m)[0]
+        del fo
+        if faults[name] <= DP_GRAD_RTOL:
+            raise AssertionError(f"the gradient check passes a broken sync "
+                                 f"({name}: {faults[name]:.3e})")
     return {"overlapped_loss": ov_loss, "single_jit_loss": jit_loss,
             "loss_rel_diff": rel, "max_param_diff": max(diffs),
-            "params_bitwise": max(diffs) == 0.0}
+            "params_bitwise": max(diffs) == 0.0, "grad_rel": grad_rel,
+            "grad_rel_leaf": grad_leaf, "grad_rtol": DP_GRAD_RTOL,
+            "grad_rel_by_layer": by_layer,
+            "planted_fault_grad_rel": faults}
 
 
 # ---------------------------------------------------------------------------
@@ -331,8 +407,7 @@ def phase_dp_step(mesh, cfg):
 # ---------------------------------------------------------------------------
 
 
-def phase_serve(cfg, prompts=SERVE_PROMPTS, new_tokens=SERVE_NEW,
-                max_batch=SERVE_BATCH, max_len=SERVE_MAX_LEN):
+def phase_serve(cfg):
     import jax
     import numpy as np
     from repro.models import decoder
@@ -341,12 +416,12 @@ def phase_serve(cfg, prompts=SERVE_PROMPTS, new_tokens=SERVE_NEW,
     params = decoder.init(jax.random.PRNGKey(0), cfg)
     rng = np.random.default_rng(0)
     reqs = [Request(prompt=rng.integers(0, cfg.vocab, n).astype(np.int32),
-                    max_new_tokens=new_tokens) for n in prompts]
-    eng = Engine(params, cfg, max_batch=max_batch, max_len=max_len)
+                    max_new_tokens=SERVE_NEW) for n in SERVE_PROMPTS]
+    eng = Engine(params, cfg, max_batch=SERVE_BATCH, max_len=SERVE_MAX_LEN)
     t0 = time.perf_counter()
     done = eng.run(reqs)
     wall = time.perf_counter() - t0
-    if len(done) != len(reqs) or any(len(r.out_tokens) != new_tokens
+    if len(done) != len(reqs) or any(len(r.out_tokens) != SERVE_NEW
                                      for r in done):
         raise AssertionError("not every request got its tokens")
     # no-cache greedy recompute of every request, teacher-forced on the
@@ -375,9 +450,9 @@ def phase_serve(cfg, prompts=SERVE_PROMPTS, new_tokens=SERVE_NEW,
     if not exact >= ties:
         raise AssertionError(f"only {exact} of {exact + ties} tokens are "
                              f"the recompute's argmax")
-    return {"requests": len(done), "new_tokens": new_tokens,
-            "prompt_lengths": sorted(set(prompts)), "exact_tokens": exact,
-            "near_ties": ties, "serve_wall_s": wall,
+    return {"requests": len(done), "new_tokens": SERVE_NEW,
+            "prompt_lengths": sorted(set(SERVE_PROMPTS)),
+            "exact_tokens": exact, "near_ties": ties, "serve_wall_s": wall,
             "ticks": eng.metrics()["ticks"]}
 
 

@@ -42,7 +42,8 @@ Codecs (stated elementwise round-trip bound, relative to ``max|slice|``):
 
 Codecs whose :class:`CodecMeta` sets ``fused=True`` additionally register
 Pallas lowerings in ``repro.kernels.codec`` that fuse encode+error-feedback
-into one memory pass and decode+reduce into another;
+into one memory pass (two for fp8_sim, whose slice max is read first) and
+decode+reduce into another;
 :meth:`Codec.encode_with_feedback` / :meth:`Codec.encode_residual` /
 :meth:`Codec.decode_reduce` route through them unless
 :func:`jnp_reference_paths` disables fusion (the conformance A/B switch).
@@ -104,7 +105,8 @@ class CodecMeta:
                     :func:`admissible`.
     fused:          the codec registers Pallas fused lowerings
                     (encode+error-feedback and decode+reduce in one memory
-                    pass each) in ``repro.kernels.codec``; the hot-path
+                    pass each, plus a slice-max read for a per-slice
+                    scale) in ``repro.kernels.codec``; the hot-path
                     methods route through them while :func:`fused_enabled`.
     fused_flops_per_elem: modeled per-element work of the *fused* path —
                     fewer memory passes than ``flops_per_elem`` prices
@@ -381,7 +383,11 @@ class Fp8SimCodec(Codec):
     meta = CodecMeta("fp8_sim",
                      wire_ratio=4.0 * (1.0 - 1e-3) if _HAVE_FP8 else 1.0,
                      flops_per_elem=2.0, error_bound=2.0 ** -4,
-                     fused=_HAVE_FP8, fused_flops_per_elem=1.0)
+                     # the fused encode reads its payload twice (the
+                     # slice max, then the kernel): 21 B/elem against
+                     # 13 for one pass (kernels.codec.memory_traffic),
+                     # so the one-pass price 1.0 scales to 1.6
+                     fused=_HAVE_FP8, fused_flops_per_elem=1.6)
 
     def encode(self, x2d):
         x2d = x2d.astype(jnp.float32)

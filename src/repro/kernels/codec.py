@@ -12,7 +12,9 @@ fuses them:
                             residual) ONCE; emit the wire blocks, the scales
                             AND the updated residual from registers — the
                             intermediate ``decode(encode(x))`` tensor never
-                            materializes in HBM.
+                            materializes in HBM. (fp8_sim scales by the
+                            slice max, so its payload is read once more, by
+                            the reduction ahead of the kernel.)
   decode + reduce           accumulate the ``W`` incoming wire slices into
                             f32 registers directly (the reduction runs over
                             the grid's inner axis into a revisited output
@@ -57,19 +59,16 @@ _HAVE_FP8 = hasattr(jnp, "float8_e4m3fn")
 _HALF = BLOCK // 2
 
 # Each grid step handles a tile of R quantization blocks laid out as rows
-# of an (N, BLOCK) array. R is a multiple of 32 so int8/uint8 tiles fill
-# whole (32, 128) vregs (f32 tiles need only 8); 512 rows keep a step's
-# double-buffered tiles at a few MiB of VMEM.
-_ROW_ALIGN = 32
+# of an (N, BLOCK) array: all N rows when N <= 512 (a block that spans the
+# array is always legal), else 512 rows, a multiple of 32 so int8/uint8
+# tiles fill whole (32, 128) vregs. The last tile may run past N: rows are
+# independent and Pallas drops the writes of the rows past the edge. 512
+# rows keep a step's double-buffered tiles at a few MiB of VMEM.
 _MAX_ROWS = 512
 
 
-def _round_up(n: int, m: int) -> int:
-    return -(-n // m) * m
-
-
 def _tile_rows(n_rows: int) -> int:
-    return min(_MAX_ROWS, _round_up(max(n_rows, 1), _ROW_ALIGN))
+    return min(n_rows, _MAX_ROWS)
 
 
 def _pad_axis(a, axis: int, n: int):
@@ -97,13 +96,10 @@ def _encode_call(quant, rows, wire_dtype, wire_cols: int, interpret: bool,
     row reduction and writes it out)."""
     N = rows[0].shape[0]
     R = _tile_rows(N)
-    Np = _round_up(N, R)
     row = pl.BlockSpec((R, BLOCK), lambda i: (i, 0))
     col = pl.BlockSpec((R, 1), lambda i: (i, 0))
-    args = [_pad_axis(a, 0, Np) for a in rows]
     given = scale_rows is not None
-    if given:
-        args.append(_pad_axis(scale_rows, 0, Np))
+    args = list(rows) + ([scale_rows] if given else [])
     n_x = len(rows)
 
     def kernel(*refs):
@@ -118,18 +114,17 @@ def _encode_call(quant, rows, wire_dtype, wire_cols: int, interpret: bool,
         outs[-1][...] = c - deq
 
     out_specs = [pl.BlockSpec((R, wire_cols), lambda i: (i, 0))]
-    out_shape = [jax.ShapeDtypeStruct((Np, wire_cols), wire_dtype)]
+    out_shape = [jax.ShapeDtypeStruct((N, wire_cols), wire_dtype)]
     if not given:
         out_specs.append(col)
-        out_shape.append(jax.ShapeDtypeStruct((Np, 1), jnp.float32))
+        out_shape.append(jax.ShapeDtypeStruct((N, 1), jnp.float32))
     out_specs.append(row)
-    out_shape.append(jax.ShapeDtypeStruct((Np, BLOCK), jnp.float32))
-    outs = pl.pallas_call(
-        kernel, grid=(Np // R,),
+    out_shape.append(jax.ShapeDtypeStruct((N, BLOCK), jnp.float32))
+    return pl.pallas_call(
+        kernel, grid=(pl.cdiv(N, R),),
         in_specs=[row] * n_x + ([col] if given else []),
         out_specs=out_specs, out_shape=out_shape, interpret=interpret,
     )(*args)
-    return [o[:N] for o in outs]
 
 
 def _decode_call(dequant, wire3, scale3, interpret: bool):
@@ -138,7 +133,6 @@ def _decode_call(dequant, wire3, scale3, interpret: bool):
     f32 ``(nb, BLOCK)`` output tile revisited along the inner grid axis."""
     W, nb, C = wire3.shape
     R = _tile_rows(nb)
-    nbp = _round_up(nb, R)
 
     def kernel(q_ref, s_ref, o_ref):
         @pl.when(pl.program_id(1) == 0)
@@ -148,14 +142,14 @@ def _decode_call(dequant, wire3, scale3, interpret: bool):
         o_ref[...] += dequant(q_ref[0]) * s_ref[0]
 
     out = pl.pallas_call(
-        kernel, grid=(nbp // R, W),
+        kernel, grid=(pl.cdiv(nb, R), W),
         in_specs=[pl.BlockSpec((1, R, C), lambda b, w: (w, b, 0)),
                   pl.BlockSpec((1, R, 1), lambda b, w: (w, b, 0))],
         out_specs=pl.BlockSpec((R, BLOCK), lambda b, w: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((nbp, BLOCK), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((nb, BLOCK), jnp.float32),
         interpret=interpret,
-    )(_pad_axis(wire3, 1, nbp), _pad_axis(scale3, 1, nbp))
-    return out[:nb].reshape(-1)
+    )(wire3, scale3)
+    return out.reshape(-1)
 
 
 def _block_encoder(quant, wire_dtype, wire_cols: int):
@@ -257,8 +251,10 @@ int4_decode_reduce = _block_decoder(_i4_dequant)
 
 # ---------------------------------------------------------------------------
 # fp8_sim: e4m3 cast against a per-slice scale. The slice amax is one
-# reduction pass ahead of the kernel; the kernel then runs the same row
-# tiles as the block codecs with each row's slice scale as an input.
+# reduction pass ahead of the kernel, a second read of the payload (a
+# slice can outgrow VMEM, so the kernel cannot hold it for one pass); the
+# kernel then runs the same row tiles as the block codecs with each row's
+# slice scale as an input.
 # ---------------------------------------------------------------------------
 
 
@@ -313,11 +309,15 @@ class CodecLowering:
     encode_feedback(x2d, err) -> (comp, new_err)   one pass over x + err
     encode_residual(x2d)      -> (comp, residual)  one pass over x
     decode_reduce(comp, L)    -> (L,) f32          one pass over the wire
+
+    ``amax_pass``: the codec scales by a per-slice max, so both encodes
+    read their payload once more, in a reduction ahead of the kernel.
     """
 
     name: str
     encode: Callable
     decode: Callable
+    amax_pass: bool = False
 
     def encode_feedback(self, x2d, err):
         return self.encode(x2d, err, interpret=_interpret())
@@ -340,7 +340,8 @@ def _register(lw: CodecLowering) -> CodecLowering:
 _register(CodecLowering("int8_block", int8_encode, int8_decode_reduce))
 _register(CodecLowering("int4_block", int4_encode, int4_decode_reduce))
 if _HAVE_FP8:
-    _register(CodecLowering("fp8_sim", fp8_encode, fp8_decode_reduce))
+    _register(CodecLowering("fp8_sim", fp8_encode, fp8_decode_reduce,
+                            amax_pass=True))
 
 
 def lowering(name: str) -> Optional[CodecLowering]:
@@ -359,12 +360,16 @@ def fused_codec_names() -> Tuple[str, ...]:
 
 
 def memory_traffic(wire_bytes_per_elem: float, n_elems: int,
-                   W: int = 8) -> Dict[str, Dict[str, float]]:
+                   W: int = 8, amax_pass: bool = False
+                   ) -> Dict[str, Dict[str, float]]:
     """HBM bytes moved per stage for ``n_elems`` f32 payload elements.
 
     jnp encode+feedback: add (r8 w4), encode (r4 w b), decode for the
     residual (r b w4), subtract (r8 w4) — every intermediate round-trips
     HBM. Fused: read x + err once (r8), write wire + residual (w b+4).
+    With ``amax_pass`` (a per-slice scale, :attr:`CodecLowering.amax_pass`)
+    both read the payload once more for its max: jnp the corrected sum it
+    wrote (r4), the fused path x + err, added inside the reduction (r8).
 
     jnp decode+reduce over ``W`` wire slices: dequantize (r b w4) then
     ``sum(axis=0)`` (r4 w 4/W) per wire element. Fused: read the wire
@@ -374,8 +379,9 @@ def memory_traffic(wire_bytes_per_elem: float, n_elems: int,
     n = float(n_elems)
     return {
         "encode_feedback": {
-            "jnp_bytes": n * (8 + 4 + 4 + b + b + 4 + 8 + 4),
-            "fused_bytes": n * (8 + b + 4),
+            "jnp_bytes": n * (8 + 4 + 4 + b + b + 4 + 8 + 4
+                              + (4 if amax_pass else 0)),
+            "fused_bytes": n * (8 + b + 4 + (8 if amax_pass else 0)),
         },
         "decode_reduce": {
             "jnp_bytes": n * (b + 4 + 4 + 4.0 / W),

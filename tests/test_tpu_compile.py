@@ -19,6 +19,9 @@ from repro.launch.mesh import make_mesh
 
 BUCKET_ELEMS = (4 << 20) // 4       # one 4 MiB f32 gradient bucket
 SLICES = 4
+# fewer rows than one (32, 128) int8 vreg tile, and 540 rows: a full
+# 512-row tile and a partial edge tile
+EDGE_SHAPES = [(1, 37 * compress.BLOCK), (3, 46000)]
 
 
 @pytest.fixture(scope="module")
@@ -84,6 +87,19 @@ def test_codec_decode_reduce_compiles_for_v5e(name, one_chip):
     L = BUCKET_ELEMS // SLICES
     comp = jax.eval_shape(compress.codec(name).encode,
                           jax.ShapeDtypeStruct((SLICES, L), jnp.float32))
+    _compile(lambda c: lw.decode(c, L, interpret=False),
+             _sds(comp, one_chip))
+
+
+@pytest.mark.parametrize("S,L", EDGE_SHAPES)
+@pytest.mark.parametrize("name", ckern.fused_codec_names())
+def test_codec_kernels_compile_for_v5e_at_edge_shapes(name, S, L,
+                                                     one_chip):
+    lw = ckern.lowering(name)
+    x = jax.ShapeDtypeStruct((S, L), jnp.float32, sharding=one_chip)
+    _compile(lambda a, e: lw.encode(a, e, interpret=False), x, x)
+    comp = jax.eval_shape(compress.codec(name).encode,
+                          jax.ShapeDtypeStruct((S, L), jnp.float32))
     _compile(lambda c: lw.decode(c, L, interpret=False),
              _sds(comp, one_chip))
 
