@@ -1,0 +1,11 @@
+"""coll_exposed_ms: the part of coll_ms in which no other operation runs on
+that chip, per step, in ms, averaged over the chips. Nothing to read where
+no collective ran."""
+
+
+def read(ctx):
+    s = ctx.summary
+    if s is None or ctx.steps <= 0 or not any(c.coll > 0 for c in s.chips):
+        return None
+    return (sum(c.coll_exposed for c in s.chips) / len(s.chips) / ctx.steps
+            * 1e-6)
