@@ -58,6 +58,7 @@ rank 0 merges the tables and writes one artifact stamped
 ``backend="multiprocess"`` / ``process_count=K``.
 """
 import argparse
+import contextlib
 import json
 import pathlib
 import time
@@ -696,27 +697,28 @@ if __name__ == "__main__":
                          "lowerings vs jnp reference: wall-clock, analytic "
                          "memory traffic, roofline seconds); with OUT_JSON, "
                          "merge a 'codec_kernels' section into the artifact")
-    ap.add_argument("--trace", metavar="OUT_JSON", default=None,
-                    help="enable the telemetry tracer for the whole run and "
-                         "export a Chrome/Perfetto trace JSON at the end "
-                         "(orthogonal to the mode flags)")
+    ap.add_argument("--trace", metavar="OUT_DIR", default=None,
+                    help="enable telemetry and take a jax.profiler trace of "
+                         "the whole run into OUT_DIR (open it in Perfetto "
+                         "or XProf; orthogonal to the mode flags)")
     args = ap.parse_args()
     if BACKEND.multiprocess and not args.calibrate:
         raise SystemExit(
             "multi-process runs support --calibrate only; the measure/"
             "overlap/codec-kernel legs are single-process benchmarks "
             "(run them without the repro.distributed launcher)")
+    with contextlib.ExitStack() as stack:
+        if args.trace:
+            telemetry.enable()
+            stack.callback(telemetry.disable)
+            stack.enter_context(jax.profiler.trace(args.trace))
+        if args.calibrate:
+            calibrate_mode(args.calibrate)
+        elif args.overlap is not None:
+            overlap_mode(args.overlap or None)
+        elif args.codec_kernels is not None:
+            codec_kernel_mode(args.codec_kernels or None)
+        else:
+            measure_mode()
     if args.trace:
-        telemetry.enable()
-    if args.calibrate:
-        calibrate_mode(args.calibrate)
-    elif args.overlap is not None:
-        overlap_mode(args.overlap or None)
-    elif args.codec_kernels is not None:
-        codec_kernel_mode(args.codec_kernels or None)
-    else:
-        measure_mode()
-    if args.trace:
-        trace = telemetry.export_chrome_trace(args.trace)
-        print(f"trace/artifact,0.0,{args.trace} "
-              f"events={len(trace['traceEvents'])}")
+        print(f"trace/artifact,0.0,{args.trace}")

@@ -157,13 +157,13 @@ class CollHandle:
     exactly once; a second ``wait`` is a misuse error (like MPI requests,
     which are invalidated by completion)."""
 
-    __slots__ = ("_op", "_value", "_done", "_token", "_t0")
+    __slots__ = ("_op", "_value", "_done", "_tags", "_t0")
 
-    def __init__(self, op: "PersistentOp", value, token=None, t0=0.0):
+    def __init__(self, op: "PersistentOp", value, tags=None, t0=0.0):
         self._op = op
         self._value = value
         self._done = False
-        self._token = token
+        self._tags = tags
         self._t0 = t0
 
     @property
@@ -185,19 +185,21 @@ class CollHandle:
                 f"start(x) yields one result")
         self._done = True
         self._op._inflight -= 1
-        if block:
-            jax.block_until_ready(self._value)
-        if self._token is not None:
-            # the telemetry window opened at start(): close it here, and a
-            # blocking wait is a synced wall-clock sample for the drift
-            # detector (the result is materialized — no extra device sync)
-            _tm.end(self._token)
+        if self._tags is None:  # telemetry was off at start()
             if block:
-                op = self._op
-                _tm.observe_plan(op.comm.topo, op.collective,
-                                 str(op.dtype), op._msg_nbytes, op.plan,
-                                 _time.perf_counter() - self._t0,
-                                 synced=True)
+                jax.block_until_ready(self._value)
+            return self._value
+        # a blocking wait is the host blocked on the collective
+        # (``sync_wait``), and a synced wall-clock sample for the drift
+        # detector (the result is materialized — no extra device sync)
+        with _tm.span("sync_wait" if block else "comm/wait", **self._tags):
+            if block:
+                jax.block_until_ready(self._value)
+        if block:
+            op = self._op
+            _tm.observe_plan(op.comm.topo, op.collective, str(op.dtype),
+                             op._msg_nbytes, op.plan,
+                             _time.perf_counter() - self._t0, synced=True)
         return self._value
 
 
@@ -205,9 +207,6 @@ class CollHandle:
 #: rebind-hygiene observable: re-resolving a plan must release the old op,
 #: so repeated plan crossings keep this flat instead of growing it
 _LIVE_OPS = 0
-
-#: monotone op id feeding per-op telemetry track names
-_OP_SEQ = 0
 
 
 def live_persistent_ops() -> int:
@@ -264,19 +263,10 @@ class PersistentOp:
         # (mirrors runtime._message_bytes) — the drift detector's size key
         self._msg_nbytes = (max(1, total) if collective == "broadcast"
                             else max(1, total // comm.topo.world))
-        global _LIVE_OPS, _OP_SEQ
-        _OP_SEQ += 1
-        # each op gets its own trace track, so concurrent in-flight windows
-        # (per-bucket overlap) render as parallel lanes, never stacked
-        self._track = f"comm:{collective}#{_OP_SEQ}"
-        t0 = _time.perf_counter() if _tm.enabled() else 0.0
+        global _LIVE_OPS
         self._compiled, self._in_sharding = runtime.compile_persistent(
             comm.mesh, comm.topo, collective, algo, self.shape, self.dtype,
             stacked=stacked, donate=donate, carry=self.carry, **self.kw)
-        if _tm.enabled():
-            _tm.emit(f"persistent_init/{collective}", t0,
-                     _time.perf_counter() - t0, cat="persistent",
-                     **self._tags())
         _tm.counter("comm.persistent_inits").inc()
         _LIVE_OPS += 1
 
@@ -318,10 +308,6 @@ class PersistentOp:
         self._compiled = None
         _LIVE_OPS -= 1
         _tm.counter("comm.persistent_releases").inc()
-        if _tm.enabled():
-            _tm.instant(f"persistent_release/{self.collective}",
-                        cat="persistent", starts=self.starts,
-                        **self._tags())
 
     def _check_operand(self, x, what: str = "operand"):
         if not isinstance(x, jax.Array):
@@ -335,11 +321,21 @@ class PersistentOp:
             x = runtime.to_sharding(x, self._in_sharding)
         return x
 
-    def start(self, x, carry=None) -> CollHandle:
+    def start(self, x, carry=None, **tags) -> CollHandle:
         """Dispatch one invocation of the compiled plan on ``x`` and return
         its handle immediately (no recompile, no cache lookup). A carry op
         additionally takes ``carry=state`` (same spec as ``x``) and its
-        handle's ``wait()`` returns ``(result, new_state)``."""
+        handle's ``wait()`` returns ``(result, new_state)``. ``tags``
+        (a bucket index, a step number) join the plan's on this call's
+        ``comm/start`` and wait spans when telemetry is enabled."""
+        if not _tm.enabled():
+            return CollHandle(self, self._dispatch(x, carry))
+        tags = dict(self._tags(), **tags)
+        t0 = _time.perf_counter()
+        with _tm.span("comm/start", **tags):
+            return CollHandle(self, self._dispatch(x, carry), tags, t0)
+
+    def _dispatch(self, x, carry):
         if self._released:
             raise RuntimeError(
                 f"start() on a released {self.collective} persistent op; "
@@ -357,19 +353,13 @@ class PersistentOp:
                 + ("requires carry=state" if self.carry
                    else "does not take a carry operand"))
         x = self._check_operand(x)
-        self._inflight += 1
-        self.starts += 1
-        token, t0 = None, 0.0
-        if _tm.enabled():
-            # the start->wait window rides this op's own track, so nested /
-            # concurrent bucket windows stay visible next to compute spans
-            t0 = _time.perf_counter()
-            token = _tm.begin(f"{self.collective}[{self.plan}]",
-                              cat="comm", track=self._track, **self._tags())
         if self.carry:
             carry = self._check_operand(carry, what="carry")
-            return CollHandle(self, self._compiled(x, carry), token, t0)
-        return CollHandle(self, self._compiled(x), token, t0)
+        self._inflight += 1
+        self.starts += 1
+        if self.carry:
+            return self._compiled(x, carry)
+        return self._compiled(x)
 
     def __call__(self, x, carry=None):
         """Blocking convenience: ``start(x).wait()``."""
@@ -520,22 +510,12 @@ class Communicator:
             raise ValueError(f"duplicate plan knobs {sorted(overlap)}")
         kw.update(extra)
         topo = self._require_topo()
-        t0 = _time.perf_counter() if _tm.enabled() else 0.0
-        algo_r, kw_r = runtime.resolve_algo(topo, spec.collective,
-                                            spec.algo, proto, kw,
-                                            error_budget=spec.error_budget,
-                                            selector=self.selector)
-        if _tm.enabled():
-            _tm.emit(f"plan_resolve/{spec.collective}", t0,
-                     _time.perf_counter() - t0, cat="resolve",
-                     requested=spec.algo,
-                     **_tm.plan_tags(spec.collective, algo_r,
-                                     int(kw_r.get("chunks", 1)),
-                                     str(kw_r.get("codec", "none")),
-                                     topo.group or "",
-                                     nbytes=runtime._message_bytes(
-                                         spec.collective, topo, proto)))
-        return algo_r, kw_r
+        with _tm.span("comm/plan_resolve", collective=spec.collective,
+                      requested=spec.algo):
+            return runtime.resolve_algo(topo, spec.collective, spec.algo,
+                                        proto, kw,
+                                        error_budget=spec.error_budget,
+                                        selector=self.selector)
 
     # -- blocking methods ---------------------------------------------------
 

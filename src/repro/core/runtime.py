@@ -454,6 +454,9 @@ def _construct(mesh, topo: Topology, collective: str, algo: str,
             y, ne = fn(x[0], err=e[0])
             return y[None], ne[None]
 
+        # the program's name: its HLO module, and so the profiler's
+        # ``XLA Modules`` line, reads ``jit_<collective>.<algo>``
+        body_carry.__name__ = f"{collective}.{algo}"
         spec = _in_spec(wiring.in_mode, ax)
         mapped = sharded(body_carry, mesh, in_specs=(spec, spec),
                          out_specs=(_out_spec(out_mode, ax),) * 2,
@@ -466,6 +469,7 @@ def _construct(mesh, topo: Topology, collective: str, algo: str,
         y = fn(x[0] if take_row0 else x)
         return y[None] if stack_out else y
 
+    body.__name__ = f"{collective}.{algo}"  # as body_carry's
     mapped = sharded(body, mesh, in_specs=(_in_spec(wiring.in_mode, ax),),
                      out_specs=_out_spec(out_mode, ax), check=False)
     if not jit:
@@ -522,11 +526,8 @@ def build(mesh, topo: Topology, collective: str, algo: str, *,
         _BUILD_CACHE.move_to_end(key)
         return hit
     _STATS.build_misses += 1
-    with _tm.span(f"build/{collective}", cat="build",
-                  **(_span_tags(topo, collective, algo, kw)
-                     if _tm.enabled() else {})):
-        built = _construct(mesh, topo, collective, algo, stacked, jit,
-                           donate, carry, **kw)
+    built = _construct(mesh, topo, collective, algo, stacked, jit, donate,
+                       carry, **kw)
     _BUILD_CACHE[key] = built
     _evict(_BUILD_CACHE, "build")
     return built
@@ -566,37 +567,39 @@ def run_resolved(mesh, topo: Topology, name: str, algo: str, x, *,
     methods resolve once with their own selector, then come here)."""
     key = (mesh, topo, name, algo, stacked, _kw_key(kw),
            (tuple(x.shape), str(x.dtype)), _codecs.fused_enabled())
-    tm_on = _tm.enabled()  # one global read; the disabled path adds nothing
-    t0 = _time.perf_counter() if tm_on else 0.0
+    if not _tm.enabled():  # one global read; the disabled path adds nothing
+        return _exec(key, mesh, topo, name, algo, x, stacked, kw)(x)
+    nbytes = _message_bytes(name, topo, x)
+    t0 = _time.perf_counter()
+    with _tm.span("comm/start",
+                  **_span_tags(topo, name, algo, kw, nbytes=nbytes)):
+        out = _exec(key, mesh, topo, name, algo, x, stacked, kw)(x)
+    # dispatch wall-clock only (async: the device may still be running)
+    _tm.observe_plan(topo, name, str(x.dtype), nbytes,
+                     autotune.encode_plan(algo, int(kw.get("chunks", 1)),
+                                          str(kw.get("codec", "none"))),
+                     _time.perf_counter() - t0, synced=False)
+    return out
+
+
+def _exec(key, mesh, topo: Topology, name: str, algo: str, x,
+          stacked: bool, kw: Dict[str, Any]):
+    """The exec cache's compiled callable for ``key``, compiled from
+    ``x``'s spec on a miss (inside a ``comm/compile`` span)."""
     compiled = _EXEC_CACHE.get(key)
     if compiled is not None:
         _STATS.exec_hits += 1
         _EXEC_CACHE.move_to_end(key)
-        cache = "hit"
-    else:
-        _STATS.exec_misses += 1
-        cache = "miss"
-        with (_tm.span(f"compile/{name}", cat="compile",
-                       **_span_tags(topo, name, algo, kw))
-              if tm_on else _tm.span("")):
-            jitted = build(mesh, topo, name, algo, stacked=stacked,
-                           jit=True, **kw)
-            compiled = jitted.lower(x).compile()
-        _EXEC_CACHE[key] = compiled
-        _evict(_EXEC_CACHE, "exec")
-    out = compiled(x)
-    if tm_on:
-        # dispatch wall-clock only (async: the device may still be running)
-        dt = _time.perf_counter() - t0
-        nbytes = _message_bytes(name, topo, x)
-        _tm.emit(name, t0, dt, cat="collective", cache=cache,
-                 **_span_tags(topo, name, algo, kw, nbytes=nbytes))
-        _tm.observe_plan(topo, name, str(x.dtype), nbytes,
-                         autotune.encode_plan(algo,
-                                              int(kw.get("chunks", 1)),
-                                              str(kw.get("codec", "none"))),
-                         dt, synced=False)
-    return out
+        return compiled
+    _STATS.exec_misses += 1
+    with _tm.span("comm/compile", **(_span_tags(topo, name, algo, kw)
+                                      if _tm.enabled() else {})):
+        jitted = build(mesh, topo, name, algo, stacked=stacked, jit=True,
+                       **kw)
+        compiled = jitted.lower(x).compile()
+    _EXEC_CACHE[key] = compiled
+    _evict(_EXEC_CACHE, "exec")
+    return compiled
 
 
 def input_sharding(mesh, topo: Topology, collective: str) -> NamedSharding:
@@ -684,12 +687,9 @@ def compile_persistent(mesh, topo: Topology, name: str, algo: str,
     if compiled is not None:
         _STATS.exec_hits += 1
         _EXEC_CACHE.move_to_end(key)
-        if _tm.enabled():
-            _tm.instant(f"persistent_cache_hit/{name}", cat="cache",
-                        **_span_tags(topo, name, algo, kw))
         return compiled, sharding
     _STATS.exec_misses += 1
-    with _tm.span(f"persistent_compile/{name}", cat="compile",
+    with _tm.span("comm/compile", persistent=True,
                   **(_span_tags(topo, name, algo, kw)
                      if _tm.enabled() else {})):
         jitted = build(mesh, topo, name, algo, stacked=stacked, jit=True,
@@ -788,7 +788,7 @@ def calibrate(mesh, topo: Topology,
                 if codec != _codecs.NONE:
                     kw["codec"] = codec
                 plan = autotune.encode_plan(algo, chunks, codec)
-                with _tm.span(f"calibrate/{name}/{plan}", cat="calibrate",
+                with _tm.span("comm/calibrate", plan=plan,
                               **(_span_tags(topo, name, algo, kw,
                                             nbytes=int(nbytes))
                                  if _tm.enabled() else {})):

@@ -1,5 +1,5 @@
-"""Collective telemetry: structured tracing, a metrics registry, and
-cost-model drift detection for the Communicator stack.
+"""Collective telemetry: host spans on the profiler's clock, a metrics
+registry, and cost-model drift detection for the Communicator stack.
 
 PiP-MColl's argument is about *where time goes* per collective stage; this
 module makes the reproduction report that continuously instead of through
@@ -7,17 +7,14 @@ one-off benchmark scripts. Three pieces, all **zero-overhead when
 disabled** (every instrumentation site in runtime/comm/train/serve guards
 on :func:`enabled`, a single module-global read):
 
-  1. **Tracer** — a bounded span ring buffer recording per-collective
-     lifecycle events (plan resolution, build/exec cache hit-or-miss, AOT
-     compile, persistent-op init/start/wait/release, train-step segments,
-     per-bucket overlap windows), tagged with the resolved plan
-     ``(collective, algo, chunks, codec, group tag, size bucket)``.
-     :func:`export_chrome_trace` emits Chrome/Perfetto trace-event JSON
-     (load it at ``ui.perfetto.dev`` or ``chrome://tracing``) so the
-     segmented-overlap start/wait windows become a visible timeline:
-     compute segments ride the ``main`` track and each in-flight bucket
-     rides its own ``comm:*`` track, so overlap shows up as bucket windows
-     lying *inside* the enclosing step span.
+  1. **Spans** — :func:`span` returns a ``jax.profiler.TraceAnnotation``
+     named for the layer boundary it marks (``train/fwd``, ``comm/start``,
+     ``sync_wait``, ...) with its tags as the annotation's arguments, so
+     the program's host spans land in the profiler's own trace, on the
+     same clock as the device's operations. Take a trace with
+     ``jax.profiler.trace(dir)`` while telemetry is enabled and open it in
+     Perfetto or XProf. :func:`enable` also hooks Python's cyclic
+     collector, so each collection is a ``gc`` span.
   2. **Metrics registry** — process-wide counters and fixed-bucket
      histograms (host-side only; instrumentation records on dispatch/wait
      boundaries that already exist and never inserts a device sync).
@@ -41,18 +38,17 @@ dispatch-only wall-clock (blocking-method call overhead under async
 dispatch) and are kept separately — they land in the histograms but never
 in drift verdicts, so async dispatch can't masquerade as a fast plan.
 
-The module imports only the standard library; runtime/comm/autotune are
-imported lazily inside :func:`snapshot` / :func:`drift_report`, so every
-core module may import this one without cycles.
+The module imports only the standard library; jax's profiler is imported
+by :func:`enable`, and runtime/comm/autotune lazily inside
+:func:`snapshot` / :func:`drift_report`, so every core module may import
+this one without cycles.
 """
 from __future__ import annotations
 
 import dataclasses
-import json
+import gc
 import math
-import pathlib
 import threading
-import time
 from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -61,9 +57,11 @@ from typing import Any, Dict, List, Optional, Tuple
 # ---------------------------------------------------------------------------
 
 _ENABLED = False
-_DEFAULT_CAPACITY = 65536
-
 _LOCK = threading.Lock()
+#: ``jax.profiler.TraceAnnotation``, bound by :func:`enable`
+_ANNOTATION = None
+#: the annotation of the cyclic collection in progress, if any
+_GC_SPAN = None
 
 
 def enabled() -> bool:
@@ -71,94 +69,37 @@ def enabled() -> bool:
     return _ENABLED
 
 
-def enable(capacity: Optional[int] = None) -> None:
-    """Turn the tracer + plan observation on. ``capacity`` resizes the span
-    ring buffer (existing spans are kept up to the new bound)."""
-    global _ENABLED, _SPANS
-    with _LOCK:
-        if capacity is not None and int(capacity) != _SPANS.maxlen:
-            _SPANS = deque(_SPANS, maxlen=max(1, int(capacity)))
-        _ENABLED = True
+def enable() -> None:
+    """Turn spans, the ``gc`` hook and plan observation on."""
+    global _ENABLED, _ANNOTATION
+    from jax.profiler import TraceAnnotation
+    _ANNOTATION = TraceAnnotation
+    if _gc_hook not in gc.callbacks:
+        gc.callbacks.append(_gc_hook)
+    _ENABLED = True
 
 
 def disable() -> None:
-    """Turn instrumentation off (recorded spans/metrics are kept until
-    :func:`reset`)."""
+    """Turn instrumentation off and remove the ``gc`` hook (recorded
+    metrics are kept until :func:`reset`)."""
     global _ENABLED
     _ENABLED = False
+    if _gc_hook in gc.callbacks:
+        gc.callbacks.remove(_gc_hook)
 
 
 def reset() -> None:
-    """Drop every recorded span, metric, and plan observation (enablement
-    is unchanged) — per-phase assertions start from zero after this."""
-    global _DROPPED
+    """Drop every recorded metric and plan observation (enablement is
+    unchanged) — per-phase assertions start from zero after this."""
     with _LOCK:
-        _SPANS.clear()
-        _DROPPED = 0
         _REGISTRY.reset()
         _PLAN_OBS.clear()
         _SAMPLE_COUNTERS.clear()
 
 
 # ---------------------------------------------------------------------------
-# tracer: span ring buffer -> Chrome/Perfetto trace JSON
+# spans: jax.profiler annotations
 # ---------------------------------------------------------------------------
-
-
-@dataclasses.dataclass(frozen=True)
-class Span:
-    """One completed lifecycle window. ``start`` is ``time.perf_counter``
-    seconds (exported relative to the earliest span); ``track`` is the
-    logical timeline lane (``"main"`` for compute/dispatch, ``"comm:*"``
-    for in-flight collective windows so concurrent buckets never overlap
-    on one lane)."""
-
-    name: str
-    cat: str
-    start: float
-    duration: float
-    track: str
-    args: Tuple[Tuple[str, Any], ...]
-
-    @property
-    def end(self) -> float:
-        return self.start + self.duration
-
-
-_SPANS: "deque[Span]" = deque(maxlen=_DEFAULT_CAPACITY)
-_DROPPED = 0
-
-
-def _emit(span: Span) -> None:
-    global _DROPPED
-    with _LOCK:
-        if len(_SPANS) == _SPANS.maxlen:
-            _DROPPED += 1
-        _SPANS.append(span)
-
-
-def _freeze_args(args: Dict[str, Any]) -> Tuple[Tuple[str, Any], ...]:
-    return tuple(sorted(args.items()))
-
-
-class _SpanCtx:
-    """Context manager emitting one span on exit (enabled path only)."""
-
-    __slots__ = ("name", "cat", "track", "args", "_t0")
-
-    def __init__(self, name, cat, track, args):
-        self.name, self.cat, self.track = name, cat, track
-        self.args = args
-
-    def __enter__(self):
-        self._t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc):
-        _emit(Span(self.name, self.cat, self._t0,
-                   time.perf_counter() - self._t0, self.track,
-                   _freeze_args(self.args)))
-        return False
 
 
 class _NullCtx:
@@ -174,60 +115,34 @@ class _NullCtx:
 _NULL_CTX = _NullCtx()
 
 
-def span(name: str, cat: str = "", track: str = "main", **args):
-    """``with telemetry.span("compile/allreduce", plan=...):`` — records a
-    complete span on exit. Disabled: returns a shared no-op context (no
+def span(name: str, **tags):
+    """``with telemetry.span("comm/start", collective=..., step=...):`` —
+    a host span in the profiler's trace, covering the block, with ``tags``
+    as its arguments. Disabled: returns a shared no-op context (no
     allocation beyond the call itself)."""
     if not _ENABLED:
         return _NULL_CTX
-    return _SpanCtx(name, cat, track, args)
+    return _ANNOTATION(name, **tags)
 
 
-def begin(name: str, cat: str = "", track: str = "main", **args
-          ) -> Optional[tuple]:
-    """Open a window that closes in a *different* call frame (persistent-op
-    ``start`` -> ``wait``). Returns an opaque token for :func:`end`, or
-    ``None`` when disabled (``end(None)`` is a no-op)."""
-    if not _ENABLED:
-        return None
-    return (name, cat, track, _freeze_args(args), time.perf_counter())
-
-
-def end(token: Optional[tuple]) -> None:
-    """Close a :func:`begin` window and record its span."""
-    if token is None:
-        return
-    name, cat, track, args, t0 = token
-    _emit(Span(name, cat, t0, time.perf_counter() - t0, track, args))
-
-
-def emit(name: str, start: float, duration: float, cat: str = "",
-         track: str = "main", **args) -> None:
-    """Record a span whose window the caller timed itself (hot paths that
-    read ``perf_counter`` once and only build tags when enabled)."""
+def instant(name: str, **tags) -> None:
+    """A zero-length span: a marker (a plan rebuild) in the trace."""
     if not _ENABLED:
         return
-    _emit(Span(name, cat, float(start), float(duration), track,
-               _freeze_args(args)))
+    with _ANNOTATION(name, **tags):
+        pass
 
 
-def instant(name: str, cat: str = "", track: str = "main", **args) -> None:
-    """A zero-duration marker (cache hit, release, rebind)."""
-    if not _ENABLED:
-        return
-    _emit(Span(name, cat, time.perf_counter(), 0.0, track,
-               _freeze_args(args)))
-
-
-def spans() -> List[Span]:
-    """Snapshot of the recorded spans, oldest first."""
-    with _LOCK:
-        return list(_SPANS)
-
-
-def spans_dropped() -> int:
-    """Spans evicted from the ring buffer since the last :func:`reset`."""
-    return _DROPPED
+def _gc_hook(phase: str, info: Dict[str, Any]) -> None:
+    """``gc.callbacks`` hook: one ``gc`` span per cyclic collection."""
+    global _GC_SPAN
+    if phase == "start":
+        if _ENABLED and _GC_SPAN is None:
+            _GC_SPAN = _ANNOTATION("gc", generation=info["generation"])
+            _GC_SPAN.__enter__()
+    elif _GC_SPAN is not None:
+        done, _GC_SPAN = _GC_SPAN, None
+        done.__exit__(None, None, None)
 
 
 def plan_tags(collective: str, algo: str, chunks: int = 1,
@@ -241,35 +156,6 @@ def plan_tags(collective: str, algo: str, chunks: int = 1,
     if nbytes is not None:
         tags["size_bucket"] = _bucket(int(nbytes))
     return tags
-
-
-def export_chrome_trace(path=None) -> dict:
-    """Render the span buffer as Chrome trace-event JSON (the format
-    Perfetto and ``chrome://tracing`` load). Tracks become named threads of
-    one process; spans are complete events (``ph="X"``) with microsecond
-    timestamps relative to the earliest recorded span. Returns the dict;
-    writes it to ``path`` when given."""
-    recorded = spans()
-    tracks: Dict[str, int] = {"main": 0}
-    for s in recorded:
-        tracks.setdefault(s.track, len(tracks))
-    epoch = min((s.start for s in recorded), default=0.0)
-    events: List[dict] = [
-        {"ph": "M", "pid": 0, "tid": tid, "name": "thread_name",
-         "args": {"name": track}}
-        for track, tid in tracks.items()]
-    for s in recorded:
-        events.append({
-            "name": s.name, "cat": s.cat or "repro", "ph": "X",
-            "ts": (s.start - epoch) * 1e6, "dur": s.duration * 1e6,
-            "pid": 0, "tid": tracks[s.track], "args": dict(s.args)})
-    trace = {"traceEvents": events, "displayTimeUnit": "ms",
-             "otherData": {"spans_dropped": _DROPPED}}
-    if path is not None:
-        p = pathlib.Path(path)
-        p.parent.mkdir(parents=True, exist_ok=True)
-        p.write_text(json.dumps(trace))
-    return trace
 
 
 # ---------------------------------------------------------------------------
@@ -530,8 +416,8 @@ def _process_rank() -> Tuple[int, int]:
 
 def snapshot() -> dict:
     """Unified observability snapshot: cache stats, selection stats, live
-    persistent ops, tracer occupancy, registry counters/histograms, and the
-    per-plan observation medians.
+    persistent ops, registry counters/histograms, and the per-plan
+    observation medians.
 
     Observations are process-local; rows carry this process's rank (and the
     top level a ``process`` block) so rank-0 merges of multi-controller
@@ -541,13 +427,10 @@ def snapshot() -> dict:
     ss = runtime.selection_stats()
     rank, nprocs = _process_rank()
     with _LOCK:
-        n_spans = len(_SPANS)
         obs = list(_PLAN_OBS.values())
     out = {
         "enabled": _ENABLED,
         "process": {"index": rank, "count": nprocs},
-        "tracer": {"spans": n_spans, "dropped": _DROPPED,
-                   "capacity": _SPANS.maxlen},
         "cache": {**dataclasses.asdict(cs),
                   "exec_hit_rate": cs.exec_hit_rate},
         "selection": {"prior": ss.prior, "measured": ss.measured,

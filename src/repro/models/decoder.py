@@ -178,14 +178,16 @@ def _run_block(blk, kind, j, h, cfg, rules, mesh, flags, cache, cache_index,
     mode = "decode" if cache is not None and cache_index is not None else \
         "causal"
     if kind == "attn":
-        a, nk = attention.apply(
-            blk["attn"], common.rmsnorm(h, blk["ln1"]["scale"], cfg.norm_eps),
-            cfg, rules=rules, mesh=mesh, mode=mode,
-            positions=positions, positions3=positions3,
-            cache=cache, cache_index=cache_index,
-            use_flash_decode=flags.use_flash_decode,
-            q_chunk=flags.q_chunk, kv_chunk=flags.kv_chunk)
-        h = h + a
+        with jax.named_scope("attn"):
+            a, nk = attention.apply(
+                blk["attn"],
+                common.rmsnorm(h, blk["ln1"]["scale"], cfg.norm_eps),
+                cfg, rules=rules, mesh=mesh, mode=mode,
+                positions=positions, positions3=positions3,
+                cache=cache, cache_index=cache_index,
+                use_flash_decode=flags.use_flash_decode,
+                q_chunk=flags.q_chunk, kv_chunk=flags.kv_chunk)
+            h = h + a
         new_cache = nk
     elif kind == "mamba":
         a, ns = mamba.apply(
@@ -212,14 +214,16 @@ def _run_block(blk, kind, j, h, cfg, rules, mesh, flags, cache, cache_index,
             new_cache = {"tm_shift": shift, "wkv": wkv_s, "cm_shift": shift2}
         return h, aux, new_cache
     # ffn / moe sub-block (attn & mamba kinds)
-    x2 = common.rmsnorm(h, blk["ln2"]["scale"], cfg.norm_eps)
-    if "moe" in blk:
-        f, aux = moe.apply(blk["moe"], x2, cfg, rules=rules, mesh=mesh)
-        if "ffn" in blk:  # arctic dense residual in parallel
-            f = f + mlp.apply(blk["ffn"], x2, cfg, rules=rules, mesh=mesh)
-    else:
-        f = mlp.apply(blk["ffn"], x2, cfg, rules=rules, mesh=mesh)
-    h = h + f
+    with jax.named_scope("mlp"):
+        x2 = common.rmsnorm(h, blk["ln2"]["scale"], cfg.norm_eps)
+        if "moe" in blk:
+            f, aux = moe.apply(blk["moe"], x2, cfg, rules=rules, mesh=mesh)
+            if "ffn" in blk:  # arctic dense residual in parallel
+                f = f + mlp.apply(blk["ffn"], x2, cfg, rules=rules,
+                                  mesh=mesh)
+        else:
+            f = mlp.apply(blk["ffn"], x2, cfg, rules=rules, mesh=mesh)
+        h = h + f
     return h, aux, new_cache
 
 
@@ -244,10 +248,11 @@ def embed_apply(params, tokens, cfg, *, rules=None, mesh=None,
     """The forward's embedding stage alone: token lookup (+ optional
     frontend embeds prepended). The entry segment of the backward-segmented
     train step — its VJP is the embedding-table grad bucket."""
-    h = jnp.take(params["embed"], tokens, axis=0)
-    if embeds is not None:
-        h = jnp.concatenate([embeds.astype(h.dtype), h], axis=1)
-    return constrain(h, ("batch", None, None), rules, mesh)
+    with jax.named_scope("embed"):
+        h = jnp.take(params["embed"], tokens, axis=0)
+        if embeds is not None:
+            h = jnp.concatenate([embeds.astype(h.dtype), h], axis=1)
+        return constrain(h, ("batch", None, None), rules, mesh)
 
 
 def _mrope_positions3(cfg, B, T, cache_index, positions3):
@@ -296,7 +301,8 @@ def segment_apply(params, h, cfg, lo: int, hi: int, *, rules=None,
 
     gslice = jax.tree.map(
         lambda g: jax.lax.slice_in_dim(g, lo, hi, axis=0), params["groups"])
-    h, auxs = jax.lax.scan(_remat_wrap(scan_body, flags), h, gslice)
+    with jax.named_scope("layers"):
+        h, auxs = jax.lax.scan(_remat_wrap(scan_body, flags), h, gslice)
     return h, auxs.sum()
 
 
@@ -305,9 +311,11 @@ def head_apply(params, h, cfg, *, rules=None, mesh=None,
     """The forward's output stage alone: final norm + LM head. The exit
     segment of the backward-segmented train step — its VJP is the
     (final_norm, lm_head) grad bucket plus the trunk cotangent."""
-    h = common.rmsnorm(h, params["final_norm"]["scale"], cfg.norm_eps)
-    logits = (h @ params["lm_head"]).astype(jnp.dtype(flags.logits_dtype))
-    return constrain(logits, ("batch", None, "vocab"), rules, mesh)
+    with jax.named_scope("head"):
+        h = common.rmsnorm(h, params["final_norm"]["scale"], cfg.norm_eps)
+        logits = (h @ params["lm_head"]).astype(
+            jnp.dtype(flags.logits_dtype))
+        return constrain(logits, ("batch", None, "vocab"), rules, mesh)
 
 
 def forward(params, tokens, cfg, *, rules=None, mesh=None,
@@ -335,8 +343,9 @@ def forward(params, tokens, cfg, *, rules=None, mesh=None,
             h = carry
             h, aux, _ = body(h, group, caches=None)
             return h, aux
-        h, auxs = jax.lax.scan(_remat_wrap(scan_body, flags), h,
-                               params["groups"])
+        with jax.named_scope("layers"):
+            h, auxs = jax.lax.scan(_remat_wrap(scan_body, flags), h,
+                                   params["groups"])
         new_caches = None
         aux = auxs.sum()
     else:
@@ -345,8 +354,9 @@ def forward(params, tokens, cfg, *, rules=None, mesh=None,
             group, cache_c = xs
             h, aux, nc = body(h, group, caches=cache_c)
             return h, (aux, nc)
-        h, (auxs, new_caches) = jax.lax.scan(scan_body, h,
-                                             (params["groups"], caches))
+        with jax.named_scope("layers"):
+            h, (auxs, new_caches) = jax.lax.scan(
+                scan_body, h, (params["groups"], caches))
         aux = auxs.sum()
 
     logits = head_apply(params, h, cfg, rules=rules, mesh=mesh, flags=flags)

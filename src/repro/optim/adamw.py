@@ -86,6 +86,11 @@ def _decay_mask(params):
 
 def update(params, grads, state, cfg: AdamWConfig):
     """One AdamW step. Returns (new_params, new_state, metrics)."""
+    with jax.named_scope("adamw"):
+        return _update(params, grads, state, cfg)
+
+
+def _update(params, grads, state, cfg: AdamWConfig):
     grads, gnorm = clip_by_global_norm(grads, cfg.grad_clip)
     step = state["step"] + 1
     lr = schedule_lr(cfg, step)

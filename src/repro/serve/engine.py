@@ -204,41 +204,41 @@ class Engine:
         while (queue or any(self.active)) and ticks < max_ticks:
             ticks += 1
             t_tick = time.perf_counter()
-            # admit
-            for slot in range(self.max_batch):
-                if self.active[slot] is None and queue:
-                    self._admit(queue.pop(0), slot)
-            # fused decode tick: every active slot advances one token, each
-            # at its OWN cache index (a (B,) vector): slot b's new KV row
-            # lands at lengths[b] and its attention masks to lengths[b]+1.
-            # A uniform max index would jump a freshly admitted short row
-            # past its true length, leaving uninitialized KV it then
-            # attends over (mixed-length admission corruption).
-            toks = np.zeros((self.max_batch, 1), np.int32)
-            for slot, req in enumerate(self.active):
-                if req is not None:
-                    toks[slot, 0] = req.out_tokens[-1]
-            logits, self.caches = self._decode(
-                self.params, self.caches, jnp.asarray(toks),
-                jnp.asarray(self.lengths, jnp.int32))
-            nxt = self._sync_tokens(np.asarray(logits[:, 0].argmax(-1)))
-            for slot, req in enumerate(self.active):
-                if req is None:
-                    continue
-                req.out_tokens.append(int(nxt[slot]))
-                self.lengths[slot] += 1
-                if (len(req.out_tokens) >= req.max_new_tokens or
-                        (req.eos_id is not None
-                         and req.out_tokens[-1] == req.eos_id)):
-                    done.append(req)
-                    self.active[slot] = None
+            with telemetry.span("serve/tick", tick=ticks):
+                # admit
+                for slot in range(self.max_batch):
+                    if self.active[slot] is None and queue:
+                        self._admit(queue.pop(0), slot)
+                # fused decode tick: every active slot advances one token,
+                # each at its OWN cache index (a (B,) vector): slot b's new
+                # KV row lands at lengths[b] and its attention masks to
+                # lengths[b]+1. A uniform max index would jump a freshly
+                # admitted short row past its true length, leaving
+                # uninitialized KV it then attends over (mixed-length
+                # admission corruption).
+                toks = np.zeros((self.max_batch, 1), np.int32)
+                for slot, req in enumerate(self.active):
+                    if req is not None:
+                        toks[slot, 0] = req.out_tokens[-1]
+                logits, self.caches = self._decode(
+                    self.params, self.caches, jnp.asarray(toks),
+                    jnp.asarray(self.lengths, jnp.int32))
+                nxt = self._sync_tokens(np.asarray(logits[:, 0].argmax(-1)))
+                for slot, req in enumerate(self.active):
+                    if req is None:
+                        continue
+                    req.out_tokens.append(int(nxt[slot]))
+                    self.lengths[slot] += 1
+                    if (len(req.out_tokens) >= req.max_new_tokens or
+                            (req.eos_id is not None
+                             and req.out_tokens[-1] == req.eos_id)):
+                        done.append(req)
+                        self.active[slot] = None
             dt = time.perf_counter() - t_tick
             active_n = sum(r is not None for r in self.active)
             self._ticks += 1
             self._occupied_slot_ticks += active_n
             self._tick_hist.observe(dt)
-            telemetry.emit("serve/tick", t_tick, dt, cat="serve",
-                           active=active_n)
         done.extend([r for r in self.active if r is not None])
         return done
 

@@ -358,7 +358,7 @@ class OverlappedGradSync:
         self._ops: List = []
         self.errs: List = []
         self._metric_op = None
-        self._btokens: List = []  # open per-bucket telemetry windows
+        self._step = 0  # the step ensure_ops last saw: the spans' step tag
 
     def budget_at(self, step: int) -> float:
         if callable(self.error_budget):
@@ -380,7 +380,11 @@ class OverlappedGradSync:
         the persistent ops only when a plan actually changed. Plans are a
         pure function of the budget value here, so an unchanged budget
         (always, for a float knob) skips the cost-model walk entirely."""
-        budget = self.budget_at(step)
+        self._step = int(step)
+        with _tm.span("train/ensure_ops", step=self._step):
+            self._ensure_ops(self.budget_at(step))
+
+    def _ensure_ops(self, budget: float) -> None:
         if self._plans is not None and budget == self._last_budget:
             return
         self._last_budget = budget
@@ -409,12 +413,11 @@ class OverlappedGradSync:
             self._metric_op = self.comm.allreduce_init(
                 shape=(world, self.metric_len), dtype=jnp.float32,
                 algo=mname, chunks=mkw.get("chunks"))
-        self._btokens = [None] * len(self._ops)
         if self._plans is not None:
             self.rebuilds += 1
             _tm.counter("train.bucket_rebuilds").inc()
             if _tm.enabled():
-                _tm.instant("bucket_rebuild", cat="train", step=int(step),
+                _tm.instant("train/bucket_rebuild", step=self._step,
                             budget=budget,
                             plans=",".join(op.plan for op in self._ops))
         self._plans = plans
@@ -425,17 +428,9 @@ class OverlappedGradSync:
     def start(self, i: int, payload):
         """Start bucket ``i``'s persistent allreduce (threading its EF
         carry when the plan compresses); returns the handle."""
-        op = self._ops[i]
-        if _tm.enabled():
-            # the bucket's start->wait window: one lane per bucket, so the
-            # trace shows each window nested inside the backward segments
-            # it overlaps
-            self._btokens[i] = _tm.begin(
-                f"bucket{i}[{op.plan}]", cat="bucket", track=f"bucket:{i}",
-                bucket=i, **op._tags())
-        if op.carry:
-            return op.start(payload, carry=self.errs[i])
-        return op.start(payload)
+        # errs[i] is None for a lossless bucket, whose op takes no carry
+        return self._ops[i].start(payload, carry=self.errs[i], bucket=i,
+                                  step=self._step)
 
     def wait(self, i: int, handle, block: bool = False):
         """Complete bucket ``i``: returns the reduced payload and absorbs
@@ -444,18 +439,10 @@ class OverlappedGradSync:
         if op.carry:
             y, new_err = handle.wait(block=block)
             self.errs[i] = new_err
-            self._close_bucket(i)
             if _tm.should_sample(f"ef:{id(self)}:{i}"):
                 self._observe_ef(op, y, new_err)
             return y
-        y = handle.wait(block=block)
-        self._close_bucket(i)
-        return y
-
-    def _close_bucket(self, i: int) -> None:
-        if self._btokens and self._btokens[i] is not None:
-            _tm.end(self._btokens[i])
-            self._btokens[i] = None
+        return handle.wait(block=block)
 
     @staticmethod
     def _observe_ef(op, y, new_err) -> None:
@@ -477,7 +464,8 @@ class OverlappedGradSync:
         return self.wait(i, self.start(i, payload), block=True)
 
     def start_metric(self, mvec):
-        return self._metric_op.start(mvec)
+        return self._metric_op.start(mvec, bucket="metrics",
+                                     step=self._step)
 
     def sync(self, buckets, mvec, overlap: bool = True):
         """Allreduce every bucket + the metrics vector.
@@ -800,29 +788,30 @@ class _OverlappedStep:
 
     # -- the step -----------------------------------------------------------
 
-    def _segmented_step(self, params, opt_state, batch):
+    def _segmented_step(self, params, opt_state, batch, n: int):
         """Backward newest-to-oldest, starting bucket i's allreduce before
         computing segment i+1's backward — under async dispatch bucket i's
         communication runs while the next segment's VJP executes. The
         barrier twin blocks out each bucket before touching the next
-        segment (same compiled programs, so the two are bit-identical)."""
+        segment (same compiled programs, so the two are bit-identical).
+        ``n`` is the step's number, which its spans carry."""
         gs, K = self.grad_sync, len(self.bounds)
-        with _tm.span("train/step", cat="train", mode="segmented",
+        with _tm.span("train/step", step=n, mode="segmented",
                       overlap=self.overlap):
-            with _tm.span("train/fwd", cat="train"):
+            with _tm.span("train/fwd", step=n):
                 outs = self._fwd_c(params, batch)
             hs, h_out, aux = outs[:K], outs[K], outs[K + 1]
-            with _tm.span("train/head_bwd", cat="train"):
+            with _tm.span("train/head_bwd", step=n):
                 head_flat, dh, mvec = self._head_bwd_c(params, h_out, aux,
                                                        batch)
             if self.overlap:
                 handles = [gs.start(0, head_flat)]
                 mh = gs.start_metric(mvec)
                 for j, k in enumerate(range(K - 1, -1, -1)):
-                    with _tm.span(f"train/chunk_bwd[{k}]", cat="train"):
+                    with _tm.span("train/chunk_bwd", step=n, k=k):
                         bflat, dh = self._chunk_bwd_c[k](params, hs[k], dh)
                     handles.append(gs.start(1 + j, bflat))
-                with _tm.span("train/embed_bwd", cat="train"):
+                with _tm.span("train/embed_bwd", step=n):
                     eflat = self._embed_bwd_c(params, batch, dh)
                 handles.append(gs.start(K + 1, eflat))
                 synced = [gs.wait(i, h, block=False)
@@ -832,13 +821,13 @@ class _OverlappedStep:
                 synced = [gs.run(0, head_flat)]
                 mvec_s = gs.start_metric(mvec).wait(block=True)
                 for j, k in enumerate(range(K - 1, -1, -1)):
-                    with _tm.span(f"train/chunk_bwd[{k}]", cat="train"):
+                    with _tm.span("train/chunk_bwd", step=n, k=k):
                         bflat, dh = self._chunk_bwd_c[k](params, hs[k], dh)
                     synced.append(gs.run(1 + j, bflat))
-                with _tm.span("train/embed_bwd", cat="train"):
+                with _tm.span("train/embed_bwd", step=n):
                     eflat = self._embed_bwd_c(params, batch, dh)
                 synced.append(gs.run(K + 1, eflat))
-            with _tm.span("train/apply", cat="train"):
+            with _tm.span("train/apply", step=n):
                 return self._apply_c(params, opt_state, *synced, mvec_s)
 
     def __call__(self, params, opt_state, batch, step: Optional[int] = None):
@@ -852,14 +841,14 @@ class _OverlappedStep:
         self._auto_step = int(step) + 1
         self.grad_sync.ensure_ops(int(step))
         if self.mode == "segmented":
-            return self._segmented_step(params, opt_state, batch)
-        with _tm.span("train/step", cat="train", mode="monolithic",
+            return self._segmented_step(params, opt_state, batch, int(step))
+        with _tm.span("train/step", step=int(step), mode="monolithic",
                       overlap=self.overlap):
-            with _tm.span("train/backward", cat="train"):
+            with _tm.span("train/backward", step=int(step)):
                 outs = self._backward_c(params, batch)
             synced, mvec = self.grad_sync.sync(outs[:-1], outs[-1],
                                                overlap=self.overlap)
-            with _tm.span("train/apply", cat="train"):
+            with _tm.span("train/apply", step=int(step)):
                 return self._apply_c(params, opt_state, *synced, mvec)
 
 

@@ -31,16 +31,17 @@ def cross_entropy(logits, labels, z_loss: float = 0.0):
     """logits (B,S,V) any dtype, labels (B,S) int32 (-1 = masked).
 
     fp32 log-softmax; returns (mean_loss, n_tokens)."""
-    mask = (labels >= 0)
-    labels = jnp.maximum(labels, 0)
-    lg = logits.astype(Accum)
-    lse = jax.nn.logsumexp(lg, axis=-1)
-    gold = jnp.take_along_axis(lg, labels[..., None], axis=-1)[..., 0]
-    nll = lse - gold
-    if z_loss:
-        nll = nll + z_loss * jnp.square(lse)
-    n = jnp.maximum(mask.sum(), 1)
-    return jnp.where(mask, nll, 0.0).sum() / n, n
+    with jax.named_scope("xent"):
+        mask = (labels >= 0)
+        labels = jnp.maximum(labels, 0)
+        lg = logits.astype(Accum)
+        lse = jax.nn.logsumexp(lg, axis=-1)
+        gold = jnp.take_along_axis(lg, labels[..., None], axis=-1)[..., 0]
+        nll = lse - gold
+        if z_loss:
+            nll = nll + z_loss * jnp.square(lse)
+        n = jnp.maximum(mask.sum(), 1)
+        return jnp.where(mask, nll, 0.0).sum() / n, n
 
 
 def loss_fn(params, batch, cfg, tcfg: TrainConfig, rules=None, mesh=None):

@@ -3,12 +3,13 @@
 Usage: telemetry_check.py N P   (run under XLA_FLAGS device_count = N*P)
 
 Asserts:
-  1. a segmented-overlapped train step run with the tracer on produces a
-     Perfetto-exportable trace whose every backward stage (fwd, head_bwd,
-     per-chunk chunk_bwd, embed_bwd, apply) is a span nested inside the
-     enclosing train/step window, with the per-bucket allreduce start/wait
-     windows on their own bucket:<i> tracks inside the same window (the
-     overlap timeline the tentpole promises);
+  1. a segmented-overlapped train step run with telemetry on, under a
+     ``jax.profiler`` trace, puts every backward stage (fwd, head_bwd,
+     per-chunk chunk_bwd, embed_bwd, apply) in the profiler's trace as a
+     span nested inside the enclosing train/step span, with one
+     ``comm/start`` span per bucket allreduce (and the metrics vector),
+     tagged with its plan and bucket, inside the same window (the overlap
+     timeline on the device's clock);
   2. the drift detector flags a poisoned tuning-table row (a fake-fast
      entry that hijacks selection) and ``Selector.ingest`` repairs the
      table from the observed medians so ``choose`` recovers;
@@ -16,7 +17,7 @@ Asserts:
      tracer is disabled (stripped-replica baseline, min-of-medians);
   4. ``snapshot()`` unifies cache/selection/live-op observables non-trivially.
 """
-import json
+import glob
 import sys
 import tempfile
 
@@ -61,49 +62,43 @@ step = manual_step.make_overlapped_train_step(
 for _ in range(2):  # compile + settle shardings outside the traced window
     params, opt, m = step(params, opt, batch)
     jax.block_until_ready((params, m["loss"]))
-telemetry.reset()  # the trace below covers exactly one steady-state step
-params, opt, m = step(params, opt, batch)
-jax.block_until_ready((params, m["loss"]))
+telemetry.reset()
+with tempfile.TemporaryDirectory() as out:
+    # the trace covers exactly one steady-state step
+    with jax.profiler.trace(out):
+        params, opt, m = step(params, opt, batch)
+        jax.block_until_ready((params, m["loss"]))
+    (xplane,) = glob.glob(f"{out}/**/*.xplane.pb", recursive=True)
+    profile = jax.profiler.ProfileData.from_file(xplane)
 
-spans = telemetry.spans()
 by_name = {}
-for s in spans:
-    by_name.setdefault(s.name, []).append(s)
-(step_span,) = by_name["train/step"]
+for plane in profile.planes:
+    if plane.name.startswith("/host:"):
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(("train/", "comm/")):
+                    by_name.setdefault(e.name, []).append(
+                        (e.start_ns, e.end_ns, dict(e.stats)))
+spans = [s for v in by_name.values() for s in v]
+((lo, hi, _),) = by_name["train/step"]
 n_chunks = len(step.bounds)
-stage_names = (["train/fwd", "train/head_bwd"]
-               + [f"train/chunk_bwd[{k}]" for k in range(n_chunks)]
-               + ["train/embed_bwd", "train/apply"])
+assert sorted(st["k"] for *_, st in by_name["train/chunk_bwd"]) == \
+    list(range(n_chunks))
+stage_names = ["train/fwd", "train/head_bwd", "train/chunk_bwd",
+               "train/embed_bwd", "train/apply"]
 for name in stage_names:
-    (s,) = by_name[name]
-    assert s.track == "main", (name, s.track)
-    assert (step_span.start <= s.start
-            and s.end <= step_span.end + 1e-9), \
-        (name, s.start, s.end, step_span.start, step_span.end)
-# per-bucket overlap windows: every bucket span rides its own track and
-# lies inside the step window (these ARE the hidden-communication windows)
-bucket_spans = [s for s in spans if s.cat == "bucket" and s.duration > 0.0]
+    for s, e, _ in by_name[name]:
+        assert lo <= s and e <= hi, (name, s, e, lo, hi)
+# one comm/start per bucket allreduce and the metrics vector, each inside
+# the step window: the bucket starts interleave with the backward stages
 n_buckets = len(step.grad_sync.slices)
-assert len(bucket_spans) == n_buckets, (len(bucket_spans), n_buckets)
-assert len({s.track for s in bucket_spans}) == n_buckets
-for s in bucket_spans:
-    assert s.track.startswith("bucket:"), s.track
-    assert (step_span.start <= s.start
-            and s.end <= step_span.end + 1e-9), (s.name, s.track)
-    tags = dict(s.args)
-    assert tags["collective"] == "allreduce" and tags["algo"], tags
-
-# Perfetto export round-trip: named tracks + the same nesting by tid
-with tempfile.NamedTemporaryFile("r", suffix=".json") as f:
-    trace = telemetry.export_chrome_trace(f.name)
-    loaded = json.load(open(f.name))
-assert loaded == trace
-names = {e["args"]["name"] for e in loaded["traceEvents"]
-         if e["ph"] == "M"}
-assert "main" in names and any(n.startswith("bucket:") for n in names)
-evs = [e for e in loaded["traceEvents"] if e["ph"] == "X"]
-assert {e["name"] for e in evs} >= set(stage_names) | {"train/step"}
-assert all(e["ts"] >= 0.0 and e["dur"] >= 0.0 for e in evs)
+starts = by_name["comm/start"]
+assert len(starts) == n_buckets + 1, (len(starts), n_buckets)
+assert sorted(str(st["bucket"]) for *_, st in starts) == sorted(
+    [str(i) for i in range(n_buckets)] + ["metrics"])
+for s, e, st in starts:
+    assert lo <= s and e <= hi, st
+    assert st["collective"] == "allreduce" and st["algo"], st
 
 # --- 2. drift detector flags a poisoned row; ingest repairs it ------------
 telemetry.reset()
@@ -139,8 +134,9 @@ assert not any(r.plan == victim
                for r in telemetry.drifted_plans(selector=sel))
 
 # --- 3. disabled-path overhead guard: the telemetry hooks left in the
-# persistent-op hot path (an enabled() read in start, a None-token check in
-# wait) must cost < 2% of a start/wait round trip when telemetry is off.
+# persistent-op hot path (the span tags a caller passes, an enabled() read
+# and a _dispatch frame in start, a None-tags check in wait) must cost
+# < 2% of a start/wait round trip when telemetry is off.
 #
 # Measured in two parts because an end-to-end A/B subtraction cannot
 # resolve 2% here: an A/A control (timing the SAME function in both slots
@@ -178,22 +174,30 @@ def stripped_once():
     jax.block_until_ready(v)
 
 
-def hook_lines_once():
+def dispatch_frame(x, carry):
+    # stands for the PersistentOp._dispatch call that start() makes
+    return x
+
+
+def hook_lines_once(x, carry=None, **tags):
     # exactly what telemetry adds to a disabled start/wait round trip: the
-    # enabled() read in start, the (token, t0) defaults, and the None-token
-    # check in wait
+    # caller's span tags packed into **tags (OverlappedGradSync passes
+    # bucket= and step=), the enabled() read in start, the _dispatch call
+    # frame, the handle's (tags, t0) defaults, and the None-tags check in
+    # wait
     if telemetry.enabled():
         raise AssertionError("telemetry must be disabled here")
-    token, t0 = None, 0.0
-    if token is not None:
+    v = dispatch_frame(x, carry)
+    handle_tags, t0 = None, 0.0
+    if handle_tags is not None:
         raise AssertionError
-    return t0
+    return v
 
 
 HOOK_REPS = 200_000
 t0 = _time.perf_counter()
-for _ in range(HOOK_REPS):
-    hook_lines_once()
+for i in range(HOOK_REPS):
+    hook_lines_once(xb, bucket=i, step=i)
 hook_s = (_time.perf_counter() - t0) / HOOK_REPS
 
 # round trip: block-averaged so per-call scheduling noise amortizes
@@ -232,7 +236,7 @@ telemetry.enable()
 
 # --- 4. unified snapshot --------------------------------------------------
 snap = telemetry.snapshot()
-assert snap["enabled"] and snap["tracer"]["spans"] > 0
+assert snap["enabled"]
 assert snap["cache"]["exec_hits"] > 0
 assert snap["selection"]["total"] > 0 and snap["selection"]["by_choice"]
 assert any(p["collective"] == "allreduce" and p["samples"] >= 2

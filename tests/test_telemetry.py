@@ -1,12 +1,15 @@
-"""Telemetry: tracer, metrics registry, drift detection, and the
-disabled-path invariance guarantees.
+"""Telemetry: spans on the profiler's clock, metrics registry, drift
+detection, named scopes, and the disabled-path invariance guarantees.
 
-Runs on 1-device meshes (degenerate topology); the 8-device acceptance leg
-(nested train-step spans in the Perfetto trace, poisoned-table drift +
-ingest repair, hot-path overhead guard) is tests/checks/telemetry_check.py.
+Spans are read back from one CPU ``jax.profiler`` capture per module
+(:func:`capture`). Runs on 1-device meshes (degenerate topology); the
+8-device acceptance leg (nested train-step spans in the profiler trace,
+poisoned-table drift + ingest repair, hot-path overhead guard) is
+tests/checks/telemetry_check.py.
 """
-import json
-import warnings
+import contextlib
+import gc
+import re
 
 import jax
 import jax.numpy as jnp
@@ -18,6 +21,100 @@ from repro.core.comm import Communicator
 from repro.core.topology import Topology
 from repro.launch.mesh import make_mesh
 from subproc import run_check
+
+#: span names the program emits outside the train/, comm/ and serve/
+#: families
+PROGRAM_SPANS = ("sync_wait", "gc")
+
+
+def _is_program_span(name):
+    return name in PROGRAM_SPANS or name.startswith(
+        ("train/", "comm/", "serve/"))
+
+
+def _tiny_overlapped_step(mesh, topo):
+    from repro.configs import reduced_config
+    from repro.models import decoder
+    from repro.models.decoder import RunFlags
+    from repro.optim import adamw
+    from repro.train import manual_step
+    from repro.train.step import TrainConfig
+    cfg = reduced_config("smollm-360m")
+    ocfg = adamw.AdamWConfig(lr=1e-3, warmup_steps=0, total_steps=10,
+                             schedule="constant", grad_clip=1e9)
+    tcfg = TrainConfig(optimizer=ocfg, flags=RunFlags(remat="none"))
+    key = jax.random.PRNGKey(0)
+    batch = {"tokens": jax.random.randint(key, (2, 16), 0, cfg.vocab),
+             "labels": jax.random.randint(jax.random.PRNGKey(1), (2, 16),
+                                          0, cfg.vocab)}
+    params = decoder.init(key, cfg)
+    opt = adamw.init(params, ocfg)
+    # one layer's gradient per bucket: two chunk programs
+    step = manual_step.make_overlapped_train_step(
+        cfg, tcfg, mesh, topo, algo="pip_mcoll", bucket_bytes=1 << 18,
+        segmented=True)
+    return step, params, opt, batch
+
+
+@pytest.fixture(scope="module")
+def capture(tmp_path_factory):
+    """One profiler capture of everything the span tests read: an
+    overlapped train step and a blocking persistent wait inside a
+    harness-style ``dispatch`` span, a forced collection with telemetry on
+    (``t/gc_on``) and off (``t/gc_off``), and the same persistent op with
+    telemetry disabled (``t/off``). Returns (events, step): events as
+    (name, start_ns, end_ns, stats) from every host plane."""
+    from jax.profiler import ProfileData
+    mesh, topo = _mesh_topo()
+    comm = Communicator(mesh, topo)
+    x = jnp.arange(64, dtype=jnp.float32).reshape(1, 64)
+    op = comm.allreduce_init(x, algo="pip_mcoll")
+    step, params, opt, batch = _tiny_overlapped_step(mesh, topo)
+    params, opt, m = step(params, opt, batch, step=0)  # compile outside
+    jax.block_until_ready(m["loss"])
+    op.start(x).wait()
+    out = tmp_path_factory.mktemp("trace")
+    telemetry.reset()
+    try:
+        with jax.profiler.trace(str(out)):
+            telemetry.enable()
+            with jax.profiler.TraceAnnotation("dispatch"):
+                params, opt, m = step(params, opt, batch, step=7)
+                op.start(x, bucket=99, step=7).wait(block=True)
+            jax.block_until_ready(m["loss"])
+            with jax.profiler.TraceAnnotation("t/gc_on"):
+                gc.collect()
+            telemetry.disable()
+            with jax.profiler.TraceAnnotation("t/gc_off"):
+                gc.collect()
+            with jax.profiler.TraceAnnotation("t/off"):
+                with telemetry.span("comm/never"):
+                    op.start(x).wait(block=True)
+                    telemetry.instant("comm/never_either")
+    finally:
+        telemetry.disable()
+        telemetry.reset()
+    (path,) = out.glob("**/*.xplane.pb")
+    events = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    keep = _is_program_span(e.name) or e.name.startswith(
+                        "comm/never")
+                    events.append((e.name, e.start_ns, e.end_ns,
+                                   dict(e.stats) if keep else {}))
+    return events, step
+
+
+def _named(events, name):
+    return [e for e in events if e[0] == name]
+
+
+def _inside(events, outer):
+    """Events that lie inside the (single) event named ``outer``."""
+    ((_, lo, hi, _),) = _named(events, outer)
+    return [e for e in events if lo <= e[1] and e[2] <= hi]
 
 
 @pytest.fixture(autouse=True)
@@ -42,69 +139,76 @@ def _mesh_topo():
 
 def test_disabled_tracer_records_nothing_and_allocates_no_context():
     assert not telemetry.enabled()
-    ctx = telemetry.span("x", cat="test", plan="p")
+    ctx = telemetry.span("x", plan="p")
     assert ctx is telemetry.span("y")  # shared null context, no allocation
     with ctx:
         pass
-    assert telemetry.begin("x") is None
-    telemetry.end(None)
-    telemetry.emit("x", 0.0, 1.0)
     telemetry.instant("x")
     telemetry.observe_plan(Topology(1, 1), "allreduce", "float32", 64,
                            "pip_mcoll", 1e-3)
-    assert telemetry.spans() == []
     assert telemetry.plan_observations() == []
     assert not telemetry.should_sample("k", every=1)
+    assert telemetry._gc_hook not in gc.callbacks
 
 
-def test_span_and_begin_end_record_tagged_windows():
+def test_enabled_span_is_a_tagged_profiler_annotation():
     telemetry.enable()
-    with telemetry.span("build/allreduce", cat="build", plan="pip_mcoll"):
-        pass
-    tok = telemetry.begin("allreduce[pip_mcoll]", cat="comm",
-                          track="comm:allreduce#1", bucket=0)
-    telemetry.end(tok)
-    s1, s2 = telemetry.spans()
-    assert s1.name == "build/allreduce" and s1.track == "main"
-    assert dict(s1.args)["plan"] == "pip_mcoll"
-    assert s2.track == "comm:allreduce#1" and s2.duration >= 0.0
-    assert s2.start >= s1.start
+    ann = telemetry.span("comm/start", collective="allreduce", bucket=2)
+    assert isinstance(ann, jax.profiler.TraceAnnotation)
+    assert ann is not telemetry.span("comm/start")
+    assert telemetry._gc_hook in gc.callbacks
+    telemetry.disable()
+    assert telemetry._gc_hook not in gc.callbacks
 
 
-def test_ring_buffer_bounds_and_drop_counter():
-    telemetry.enable(capacity=8)
-    try:
-        for i in range(20):
-            telemetry.instant(f"s{i}")
-        assert len(telemetry.spans()) == 8
-        assert telemetry.spans_dropped() == 12
-        assert [s.name for s in telemetry.spans()][0] == "s12"
-    finally:
-        telemetry.enable(capacity=65536)
+def test_program_spans_nest_inside_dispatch_with_tags(capture):
+    events, step = capture
+    inner = _inside(events, "dispatch")
+    names = [e[0] for e in inner]
+    for name in ("train/step", "train/ensure_ops", "train/fwd",
+                 "train/head_bwd", "train/embed_bwd", "train/apply",
+                 "comm/start", "sync_wait"):
+        assert name in names, (name, sorted(set(names)))
+    # every span of the step carries its number
+    for name, _, _, stats in inner:
+        if name.startswith("train/"):
+            assert stats.get("step") == 7, (name, stats)
+    ks = sorted(st["k"] for n, _, _, st in inner if n == "train/chunk_bwd")
+    assert ks == list(range(len(step.bounds))) and len(ks) >= 2
+    # one comm/start per gradient bucket and the metrics vector, tagged
+    # with its plan, its bucket and the step; the waits do not block
+    starts = [st for n, _, _, st in inner if n == "comm/start"
+              and st.get("step") == 7 and st.get("bucket") != 99]
+    assert len(starts) == len(step.grad_sync.slices) + 1
+    assert {st["collective"] for st in starts} == {"allreduce"}
+    assert {st["algo"] for st in starts} == {"pip_mcoll"}
+    assert sorted(str(st["bucket"]) for st in starts) == sorted(
+        [str(i) for i in range(len(step.grad_sync.slices))] + ["metrics"])
+    assert len([n for n, *_ in inner if n == "comm/wait"]) == len(starts)
+    # the blocking wait of the persistent op is the one sync_wait
+    (sw,) = [st for n, _, _, st in inner if n == "sync_wait"]
+    assert sw["bucket"] == 99 and sw["step"] == 7
+    # nesting: the step's stage spans lie inside train/step
+    (_, lo, hi, _), = [e for e in inner if e[0] == "train/step"]
+    for n, s, e, _ in inner:
+        if n.startswith("train/") and n != "train/step":
+            if n != "train/ensure_ops":
+                assert lo <= s and e <= hi, n
 
 
-def test_export_chrome_trace_tracks_and_events(tmp_path):
-    telemetry.enable()
-    with telemetry.span("train/step", cat="train"):
-        with telemetry.span("train/fwd", cat="train"):
-            pass
-        tok = telemetry.begin("bucket0[pip_pipeline]", cat="bucket",
-                              track="bucket:0")
-        telemetry.end(tok)
-    out = tmp_path / "trace.json"
-    trace = telemetry.export_chrome_trace(out)
-    assert json.loads(out.read_text()) == trace
-    meta = {e["args"]["name"]: e["tid"] for e in trace["traceEvents"]
-            if e["ph"] == "M"}
-    assert meta["main"] == 0 and "bucket:0" in meta
-    evs = {e["name"]: e for e in trace["traceEvents"] if e["ph"] == "X"}
-    assert set(evs) == {"train/step", "train/fwd", "bucket0[pip_pipeline]"}
-    step, fwd = evs["train/step"], evs["train/fwd"]
-    assert fwd["tid"] == 0 and evs["bucket0[pip_pipeline]"]["tid"] != 0
-    # nesting by time containment on the exported microsecond timeline
-    assert step["ts"] <= fwd["ts"]
-    assert fwd["ts"] + fwd["dur"] <= step["ts"] + step["dur"] + 1e-3
-    assert trace["otherData"]["spans_dropped"] == 0
+def test_gc_span_on_forced_collect_and_not_after_disable(capture):
+    events, _ = capture
+    on = [e for e in _inside(events, "t/gc_on") if e[0] == "gc"]
+    assert on and all(e[3].get("generation") == 2 for e in on)
+    assert not [e for e in _inside(events, "t/gc_off") if e[0] == "gc"]
+
+
+def test_disabled_telemetry_traces_no_program_span(capture):
+    events, _ = capture
+    inner = _inside(events, "t/off")
+    assert any(n == "t/off" for n, *_ in inner)
+    assert not [n for n, *_ in inner if _is_program_span(n)
+                or n.startswith("comm/never")]
 
 
 def test_plan_tags_schema():
@@ -268,7 +372,8 @@ def test_outputs_and_exec_cache_keys_invariant_under_telemetry():
     assert keys_on == keys_off, "telemetry state leaked into cache keys"
     for name, out in base.items():
         np.testing.assert_array_equal(out, traced[name], err_msg=name)
-    assert len(telemetry.spans()) > 0  # it did actually trace
+    # it did observe: one dispatch sample per collective
+    assert len(telemetry.plan_observations()) == len(base)
 
 
 def test_persistent_op_bitwise_invariant_and_sampled_probe_gated():
@@ -278,11 +383,8 @@ def test_persistent_op_bitwise_invariant_and_sampled_probe_gated():
     op = comm.allreduce_init(x, algo="pip_mcoll")
     off = np.asarray(op.start(x).wait())
     telemetry.enable()
-    on = np.asarray(op.start(x).wait())
+    on = np.asarray(op.start(x, bucket=0).wait())
     np.testing.assert_array_equal(off, on)
-    # the start->wait window landed as a comm span with plan tags
-    comm_spans = [s for s in telemetry.spans() if s.cat == "comm"]
-    assert comm_spans and dict(comm_spans[-1].args)["algo"] == "pip_mcoll"
     (obs,) = [o for o in telemetry.plan_observations()
               if o.collective == "allreduce"]
     assert len(obs.samples) == 1  # blocking wait -> one synced sample
@@ -295,7 +397,7 @@ def test_snapshot_unifies_observables_when_disabled():
     comm.allreduce(jnp.ones((1, 16), jnp.float32))
     snap = telemetry.snapshot()
     assert snap["enabled"] is False
-    assert snap["tracer"]["spans"] == 0
+    assert "tracer" not in snap
     assert snap["cache"]["exec_misses"] >= 1
     assert snap["selection"]["total"] >= 1
     assert isinstance(snap["live_persistent_ops"], int)
@@ -325,3 +427,80 @@ def test_telemetry_acceptance_8dev():
     flagged + repaired by Selector.ingest, hot-path overhead < 2%."""
     out = run_check("telemetry_check.py", 8, 4, 2)
     assert "telemetry_check N=4 P=2: OK" in out
+
+
+# ---------------------------------------------------------------------------
+# named scopes: op_name metadata only
+# ---------------------------------------------------------------------------
+
+
+def _compiled_text(which):
+    """Compiled HLO of the single-jit train step or of one overlapped-step
+    program, at the tiny config on a (1, 1) mesh, without its metadata:
+    each instruction's ``metadata={...}`` and the source-location tables
+    that follow the computations (``FileNames`` on)."""
+    mesh, topo = _mesh_topo()
+    step, params, opt, batch = _tiny_overlapped_step(mesh, topo)
+    if which == "train_step":
+        from repro.train.step import train_step
+        lowered = jax.jit(lambda p, o, b: train_step(
+            p, o, b, step.cfg, step.tcfg)).lower(params, opt, batch)
+    else:
+        step._build(params, batch)
+        outs = jax.eval_shape(step._fwd_c, params, batch)
+        K = len(step.bounds)
+        lowered = (step._fwd_c.lower(params, batch) if which == "fwd" else
+                   step._head_bwd_c.lower(params, outs[K], outs[K + 1],
+                                          batch))
+    text = lowered.compile().as_text()
+    return text, re.sub(r", metadata=\{[^}]*\}", "",
+                        text.split("\nFileNames\n")[0])
+
+
+@pytest.mark.parametrize("which,scopes", [
+    ("train_step", ("embed", "layers", "attn", "mlp", "head", "xent",
+                    "adamw")),
+    ("fwd", ("embed", "layers", "attn", "mlp")),
+    ("head_bwd", ("head", "xent")),
+])
+def test_named_scopes_change_only_metadata(which, scopes, monkeypatch):
+    raw, scoped = _compiled_text(which)
+    names = set(re.findall(r'op_name="([^"]*)"', raw))
+    for scope in scopes:
+        assert any(re.search(rf"(^|/|\(){scope}(/|\))", n) for n in names), \
+            scope
+    with monkeypatch.context() as m:
+        m.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+        _, plain = _compiled_text(which)
+    assert "metadata=" not in scoped
+    assert scoped == plain
+
+
+def test_cached_executables_keep_this_builds_scopes(tmp_path):
+    """The persistent compilation cache keys on op metadata (set by
+    importing ``repro``): a build that differs only in its named scopes
+    compiles its own executable, and never loads one whose metadata name
+    another build's scopes."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    import repro  # noqa: F401
+    assert jax.config.jax_compilation_cache_include_metadata_in_key
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    old = {k: getattr(jax.config, k) for k in keys}
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    cc.reset_cache()
+    try:
+        for scope in ("adamw", None):
+            def step(x):
+                with (jax.named_scope(scope) if scope
+                      else contextlib.nullcontext()):
+                    return x * 2.0 + 1.0
+            jax.jit(step).lower(jnp.ones(8)).compile()
+    finally:
+        for k, v in old.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+    assert len(list(tmp_path.glob("jit_step-*"))) == 2
