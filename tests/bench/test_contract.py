@@ -121,15 +121,36 @@ def test_config_file_states_its_provenance(path):
             / f"{cfg['reference']}.py").is_file()
 
 
+#: the entries of ``smollm-360m.dp4`` in BENCHMARK.json, by kind
+DP4 = {"configs": {"smollm-360m"}, "workloads": {"smollm-360m.dp4"},
+       "per_layer": {"coll_ms", "coll_exposed_ms"}}
+
+
+def _add_missing(bench, kind, entries):
+    """Append to ``bench[kind]`` the entries whose names it lacks."""
+    there = {x["name"] for x in bench[kind]}
+    bench[kind] += [e for e in entries if e["name"] not in there]
+
+
 def test_dropped_in_files_are_found(tmp_path):
     """A configuration, a reference, a traffic mix, a metric and a cell's
     limits dropped into a copy are found by name, and a four-chip cell
     with its collective metrics is added by its limits and entries alone:
-    no file that is there is edited, and the rules still hold."""
+    no file that is there is edited, and the rules still hold. The copy
+    leaves out ``smollm-360m.dp4``'s entries and limits, so that the
+    four-chip cell is dropped in whether or not BENCHMARK.json has it."""
     here = tmp_path / "benchmarks" / "chip"
     shutil.copytree(ROOT / "benchmarks" / "chip", here,
                     ignore=shutil.ignore_patterns("__pycache__"))
     (tmp_path / "tests" / "bench").mkdir(parents=True)
+    bench = json.loads(json.dumps(BENCH))
+    for kind, names in DP4.items():
+        bench[kind] = [x for x in bench[kind] if x["name"] not in names]
+    for m in bench["per_layer"]:
+        if "workloads" in m:
+            m["workloads"] = [w for w in m["workloads"]
+                              if w not in DP4["workloads"]]
+    (here / "limits" / "smollm-360m.dp4.json").unlink(missing_ok=True)
     before = {p.relative_to(here): p.read_bytes()
               for p in here.rglob("*") if p.is_file()}
     (here / "reference" / "plain_lm.py").write_bytes(
@@ -145,25 +166,23 @@ def test_dropped_in_files_are_found(tmp_path):
     limits = (here / "limits" / "qwen1.5-4b.train.json").read_text()
     for cell in ("tiny-lm.short", "smollm-360m.dp4"):
         (here / "limits" / f"{cell}.json").write_text(limits)
-    bench = json.loads(json.dumps(BENCH))
-    for name in ("tiny-lm", "smollm-360m"):
-        bench["configs"].append({
-            "name": name, "source": "https://huggingface.co/x",
-            "file": f"benchmarks/chip/configs/{name}.json", "reduced": [],
-            "why": "test"})
-    bench["workloads"] += [
+    _add_missing(bench, "configs", [
+        {"name": name, "source": "https://huggingface.co/x",
+         "file": f"benchmarks/chip/configs/{name}.json", "reduced": [],
+         "why": "test"} for name in ("tiny-lm", "smollm-360m")])
+    _add_missing(bench, "workloads", [
         {"name": "tiny-lm.short", "config": "tiny-lm",
          "traffic": "short-mix", "chips": 1, "why": "test"},
         {"name": "smollm-360m.dp4", "config": "smollm-360m",
-         "traffic": "train-dp", "chips": 4, "why": "test"}]
-    bench["per_layer"].append({"name": "steps_seen", "unit": "steps",
-                               "better": "higher", "source": "device_trace",
-                               "layer": "train step", "moves": "step_ms"})
-    for name in ("coll_ms", "coll_exposed_ms"):
-        bench["per_layer"].append({
-            "name": name, "unit": "ms", "better": "lower",
-            "source": "device_trace", "layer": "collectives",
-            "moves": "step_ms", "workloads": ["smollm-360m.dp4"]})
+         "traffic": "train-dp", "chips": 4, "why": "test"}])
+    _add_missing(bench, "per_layer", [
+        {"name": "steps_seen", "unit": "steps", "better": "higher",
+         "source": "device_trace", "layer": "train step",
+         "moves": "step_ms"}] + [
+        {"name": name, "unit": "ms", "better": "lower",
+         "source": "device_trace", "layer": "collectives",
+         "moves": "step_ms", "workloads": ["smollm-360m.dp4"]}
+        for name in ("coll_ms", "coll_exposed_ms")])
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
 
     check_rules(bench, tmp_path)
@@ -175,6 +194,7 @@ def test_dropped_in_files_are_found(tmp_path):
     ctx = type("Ctx", (), {"steps": 7})()
     assert cell.readers["steps_seen"].read(ctx) == 7.0
     dp4 = cells.resolve("smollm-360m.dp4", here=here)
+    assert dp4.chips == 4
     assert {"coll_ms", "coll_exposed_ms", "steps_seen"} <= set(dp4.readers)
     # the metric with no "workloads" key reaches the cells already there
     assert "steps_seen" in cells.resolve("qwen1.5-4b.train",
